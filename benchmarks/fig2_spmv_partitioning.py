@@ -6,17 +6,17 @@ Here: COO row-wise vs COO 2D over the 8-device CPU mesh, dense input vector.
 """
 from benchmarks import common  # noqa: F401  (must be first: device count)
 
-import jax
 import numpy as np
 
 from benchmarks.common import emit, make_dense_vector, timeit
 from benchmarks.phases import phase_times, prep, shard_x
 from repro.core.semiring import PLUS_TIMES
 from repro.graphs.datasets import generate
+from repro.launch.mesh import make_mesh
 
 
 def run(quick: bool = False):
-    mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+    mesh = make_mesh((2, 4), ("dr", "dc"))
     scale = 0.05 if quick else 0.15
     sr = PLUS_TIMES
     for ds in ["face", "A302"] if not quick else ["face"]:

@@ -7,13 +7,13 @@ verified here on the 8-device mesh.
 """
 from benchmarks import common  # noqa: F401
 
-import jax
 import numpy as np
 
 from benchmarks.common import emit, make_dense_vector, timeit
 from benchmarks.phases import phase_times, prep, shard_x
 from repro.core.semiring import PLUS_TIMES
 from repro.graphs.datasets import generate
+from repro.launch.mesh import make_mesh
 
 VARIANTS = [
     ("COO", (8, 1), "row", "coo"),
@@ -25,7 +25,7 @@ CSR_VARIANT = ("CSR-R", (8, 1), "row", "csr")
 
 
 def run(quick: bool = False, include_csr: bool = True):
-    mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+    mesh = make_mesh((2, 4), ("dr", "dc"))
     sr = PLUS_TIMES
     datasets = ["face", "r-TX", "g-18"] if not quick else ["face"]
     densities = [0.01, 0.10, 0.50]

@@ -3,17 +3,17 @@
 """
 from benchmarks import common  # noqa: F401
 
-import jax
 import numpy as np
 
 from benchmarks.common import emit, make_dense_vector, timeit
 from benchmarks.phases import phase_times, prep, shard_x
 from repro.core.semiring import PLUS_TIMES
 from repro.graphs.datasets import generate
+from repro.launch.mesh import make_mesh
 
 
 def run(quick: bool = False):
-    mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+    mesh = make_mesh((2, 4), ("dr", "dc"))
     sr = PLUS_TIMES
     datasets = ["face", "A302"] if not quick else ["face"]
     for ds in datasets:

@@ -5,13 +5,13 @@ kernel-dominated.
 """
 from benchmarks import common  # noqa: F401
 
-import jax
 import numpy as np
 
 from benchmarks.common import emit, make_dense_vector, timeit
 from benchmarks.phases import phase_times, prep, shard_x
 from repro.core.semiring import BOOL_OR_AND, MIN_PLUS, PLUS_TIMES
 from repro.graphs.datasets import generate
+from repro.launch.mesh import make_mesh
 
 ALGOS = [("bfs", BOOL_OR_AND, 0.3), ("sssp", MIN_PLUS, 0.3),
          ("ppr", PLUS_TIMES, 1.0)]
@@ -23,7 +23,7 @@ def run(quick: bool = False):
     base = {}
     for d in counts:
         grid = {2: (1, 2), 4: (2, 2), 8: (2, 4)}[d]
-        mesh_axes = jax.make_mesh(grid, ("dr", "dc"))
+        mesh_axes = make_mesh(grid, ("dr", "dc"))
         for name, sr, dens in ALGOS:
             pm = prep(g, sr, grid, "csc",
                       weighted=(sr.name == "min_plus"),

@@ -41,6 +41,7 @@ from repro.graphs import datasets
 from repro.graphs.cost_model import (
     choose_merge, merge_wire_cost, strategy_grid,
 )
+from repro.launch.mesh import make_mesh
 
 MESH_GRID = (2, 4)
 ELEM_BYTES = 4                      # float32 payloads
@@ -56,7 +57,7 @@ def _graphs(quick: bool):
 
 
 def run(quick: bool = False):
-    mesh = jax.make_mesh(MESH_GRID, ("dr", "dc"))
+    mesh = make_mesh(MESH_GRID, ("dr", "dc"))
     sr = PLUS_TIMES
     for fam, g in _graphs(quick):
         rows = g.cols.astype(np.int64)    # transposed, like the engines

@@ -31,13 +31,14 @@ from repro.graphs.cost_model import trained_stump
 from repro.graphs.datasets import generate
 from repro.graphs.engine import build_engine
 from repro.graphs.multi import make_bfs_multi, make_ppr_multi, make_sssp_multi
+from repro.launch.mesh import make_mesh
 
 
 def _mesh():
     n_dev = jax.device_count()
     if n_dev <= 1:
         return None
-    return jax.make_mesh((n_dev,), ("batch",))
+    return make_mesh((n_dev,), ("batch",))
 
 
 def _engines(g, stump):

@@ -32,6 +32,7 @@ from repro.core.partition import BALANCES, partition
 from repro.core.semiring import PLUS_TIMES
 from repro.graphs import datasets
 from repro.graphs.cost_model import STRATEGIES, choose_partition, strategy_grid
+from repro.launch.mesh import make_mesh
 
 
 def _graphs(quick: bool):
@@ -44,7 +45,7 @@ def _graphs(quick: bool):
 
 
 def run(quick: bool = False):
-    mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+    mesh = make_mesh((2, 4), ("dr", "dc"))
     sr = PLUS_TIMES
     imb: dict = {}
     for fam, g in _graphs(quick):

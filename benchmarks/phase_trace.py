@@ -36,7 +36,6 @@ import json
 import os
 import time
 
-import jax
 import numpy as np
 
 from benchmarks.common import emit, make_dense_vector
@@ -47,6 +46,7 @@ from repro.core.semiring import BOOL_OR_AND
 from repro.graphs import datasets
 from repro.graphs.cost_model import estimate_phase_costs
 from repro.obs import calibrate, trace
+from repro.launch.mesh import make_mesh
 
 
 def _graphs(quick: bool):
@@ -59,7 +59,7 @@ def _graphs(quick: bool):
 
 
 def run(quick: bool = False):
-    mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+    mesh = make_mesh((2, 4), ("dr", "dc"))
     sr = BOOL_OR_AND
     n_iters = 4 if quick else 6
     cells = []

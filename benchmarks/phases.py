@@ -24,6 +24,7 @@ import numpy as np
 from repro.core.distributed import build_phase_fns  # noqa: F401  (re-export)
 from repro.core.partition import PartitionedMatrix, partition
 from repro.core.semiring import Semiring
+from repro.launch.mesh import make_mesh
 
 
 def phase_times(mesh, pm, sr, strategy, kernel, xs, timeit,
@@ -92,7 +93,7 @@ def run(quick: bool = False):
     from repro.core.semiring import BOOL_OR_AND, MIN_PLUS, PLUS_TIMES
     from repro.graphs.datasets import generate
 
-    mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+    mesh = make_mesh((2, 4), ("dr", "dc"))
     families = ["face"] if quick else ["face", "p2p-24"]
     algos = [("bfs", BOOL_OR_AND, 0.3), ("sssp", MIN_PLUS, 0.3),
              ("ppr", PLUS_TIMES, 1.0)]

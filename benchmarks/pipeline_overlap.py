@@ -25,7 +25,6 @@ from benchmarks import common  # noqa: F401  (pins device count first)
 
 import time
 
-import jax
 import numpy as np
 
 from benchmarks.common import emit, make_dense_vector, timeit
@@ -34,6 +33,7 @@ from repro.core.distributed import build_phase_fns
 from repro.core.pipeline import iterate_phases
 from repro.core.semiring import PLUS_TIMES
 from repro.graphs.datasets import generate
+from repro.launch.mesh import make_mesh
 
 # one family per Table-2 generator class: rmat / uniform / road
 FAMILIES = ["face", "p2p-24", "r-TX"]
@@ -55,7 +55,7 @@ def _wall(fn, iters: int = 5) -> float:
 
 def run(quick: bool = False, depth: int = 4):
     sr = PLUS_TIMES
-    mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+    mesh = make_mesh((2, 4), ("dr", "dc"))
     families = FAMILIES[:2] if quick else FAMILIES
     # Iteration count amortizes the per-phase sync cost the pipeline
     # removes; graph scales keep the loop latency-bound (the paper's

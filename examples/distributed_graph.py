@@ -17,6 +17,7 @@ from repro.core.distributed import make_distributed_matvec
 from repro.core.semiring import PLUS_TIMES
 from repro.graphs.datasets import generate
 from repro.graphs.engine import edge_values
+from repro.launch.mesh import make_mesh
 from repro.core.partition import partition
 
 
@@ -26,7 +27,7 @@ def main():
     n_pad = -(-g.n // 64) * 64
     vals = edge_values(g, sr, weighted=False)
     rows, cols = g.cols.astype(np.int32), g.rows.astype(np.int32)
-    mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+    mesh = make_mesh((2, 4), ("dr", "dc"))
     print(f"graph n={g.n} nnz={g.nnz}; mesh 2x4 (8 devices)")
 
     rng = np.random.default_rng(0)

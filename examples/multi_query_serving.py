@@ -17,13 +17,14 @@ import jax
 import numpy as np
 
 from repro.graphs.datasets import generate
+from repro.launch.mesh import make_mesh
 from repro.serve.graph_engine import GraphQueryServer
 
 
 def main():
     g = generate("face", scale=0.5, seed=0)
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((n_dev,), ("batch",)) if n_dev > 1 else None
+    mesh = make_mesh((n_dev,), ("batch",)) if n_dev > 1 else None
     srv = GraphQueryServer(g, batch_size=8, cache_capacity=256, mesh=mesh)
     print(f"graph n={g.n} nnz={g.nnz}; {n_dev} devices; batch=8")
 

@@ -59,8 +59,8 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.collectives import merge as merge_collective
 from repro.core.collectives import merge_chunks, plan_merge
@@ -268,7 +268,7 @@ def make_distributed_matvec(
         out_specs = P(ar, ac)
 
         fn_body = shard_map(body, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False)
+                            out_specs=out_specs, check_vma=False)
 
         def fn2d(parts, x):
             reshaped = jax.tree.map(
@@ -282,7 +282,7 @@ def make_distributed_matvec(
         raise ValueError(strategy)
 
     return shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+                     check_vma=False)
 
 
 def make_distributed_spmv(mesh: Mesh, pm: PartitionedMatrix, sr: Semiring,
@@ -351,7 +351,7 @@ def make_distributed_batched_matvec(
             return y[None]
 
         return shard_map(body, mesh=mesh, in_specs=(a_specs, P(flat)),
-                         out_specs=P(flat), check_rep=False)
+                         out_specs=P(flat), check_vma=False)
 
     if strategy == "col":
         def body(parts, x):
@@ -361,7 +361,7 @@ def make_distributed_batched_matvec(
             return y[None]
 
         return shard_map(body, mesh=mesh, in_specs=(a_specs, P(flat)),
-                         out_specs=P(flat), check_rep=False)
+                         out_specs=P(flat), check_vma=False)
 
     if strategy == "2d":
         assert (r_parts, c_parts) == (mesh.shape[ar], mesh.shape[ac]), (
@@ -378,7 +378,7 @@ def make_distributed_batched_matvec(
             body, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P((ar,), (ac,)), pm.parts),
                       P(ar, ac)),
-            out_specs=P(ar, ac), check_rep=False)
+            out_specs=P(ar, ac), check_vma=False)
 
         def fn2d(parts, x):
             reshaped = jax.tree.map(
@@ -476,7 +476,7 @@ def make_distributed_spgemm(
             body, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P((ar,), (ac,)), pm.parts),
                       P(ar, ac), P(ar, ac)),
-            out_specs=P(ar, ac), check_rep=False)
+            out_specs=P(ar, ac), check_vma=False)
 
         def fn2d(parts, b, mask=None):
             if mask is None:
@@ -496,7 +496,7 @@ def make_distributed_spgemm(
         raise ValueError(strategy)
 
     fn_body = shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+                        out_specs=out_specs, check_vma=False)
 
     def fn(parts, b, mask=None):
         if mask is None:
@@ -601,14 +601,14 @@ def build_phase_fns(mesh: Mesh, pm: PartitionedMatrix, sr: Semiring,
     if strategy == "row":
         load = shard_map(
             lambda x: jax.lax.all_gather(x, flat, tiled=True).reshape(-1)[None],
-            mesh=mesh, in_specs=P(flat), out_specs=P(flat), check_rep=False)
+            mesh=mesh, in_specs=P(flat), out_specs=P(flat), check_vma=False)
 
         def kern(parts, x_full):
             return _local_matvec(strip(parts), x_full[0], sr, kernel,
                                  loc_impl)[None]
 
         kern_sm = shard_map(kern, mesh=mesh, in_specs=(a_specs, P(flat)),
-                            out_specs=P(flat), check_rep=False)
+                            out_specs=P(flat), check_vma=False)
         fns["load"] = jax.jit(lambda parts, xs: load(xs))
         fns["kernel"] = jax.jit(
             lambda parts, xs, xf: kern_sm(parts, xf))
@@ -627,7 +627,7 @@ def build_phase_fns(mesh: Mesh, pm: PartitionedMatrix, sr: Semiring,
                 return y[None]
 
             km_sm = shard_map(kern_f, mesh=mesh, in_specs=(a_specs, P(flat)),
-                              out_specs=P(flat), check_rep=False)
+                              out_specs=P(flat), check_vma=False)
             fns["load"] = None
             fns["kernel"] = jax.jit(lambda parts, xs, _xf: km_sm(parts, xs))
             fns["retrieve_merge"] = None    # folded into the kernel program
@@ -637,11 +637,11 @@ def build_phase_fns(mesh: Mesh, pm: PartitionedMatrix, sr: Semiring,
                                      "auto")[None]
 
             kern_sm = shard_map(kern, mesh=mesh, in_specs=(a_specs, P(flat)),
-                                out_specs=P(flat), check_rep=False)
+                                out_specs=P(flat), check_vma=False)
             rm = shard_map(
                 lambda y: merge_collective(y[0], sr, col_mp)[None],
                 mesh=mesh, in_specs=P(flat), out_specs=P(flat),
-                check_rep=False)
+                check_vma=False)
             fns["load"] = None              # input already sharded
             fns["kernel"] = jax.jit(lambda parts, xs, _xf: kern_sm(parts, xs))
             fns["retrieve_merge"] = jax.jit(lambda parts, ys: rm(ys),
@@ -655,7 +655,7 @@ def build_phase_fns(mesh: Mesh, pm: PartitionedMatrix, sr: Semiring,
 
         load = shard_map(
             lambda x: jax.lax.all_gather(x[0, 0], ar, tiled=True)[None, None],
-            mesh=mesh, in_specs=P(ar, ac), out_specs=P(ar, ac), check_rep=False)
+            mesh=mesh, in_specs=P(ar, ac), out_specs=P(ar, ac), check_vma=False)
 
         if fused:
             def kern_f(parts, xc):
@@ -667,7 +667,7 @@ def build_phase_fns(mesh: Mesh, pm: PartitionedMatrix, sr: Semiring,
                 return y[None, None]
 
             km_sm = shard_map(kern_f, mesh=mesh, in_specs=(a2, P(ar, ac)),
-                              out_specs=P(ar, ac), check_rep=False)
+                              out_specs=P(ar, ac), check_vma=False)
             fns["load"] = jax.jit(
                 lambda parts, xs: load(vec_to_2d_layout(xs, pm.grid)))
             fns["kernel"] = jax.jit(
@@ -680,11 +680,11 @@ def build_phase_fns(mesh: Mesh, pm: PartitionedMatrix, sr: Semiring,
                                      "auto")[None, None]
 
             kern_sm = shard_map(kern, mesh=mesh, in_specs=(a2, P(ar, ac)),
-                                out_specs=P(ar, ac), check_rep=False)
+                                out_specs=P(ar, ac), check_vma=False)
             rm = shard_map(
                 lambda y: merge_collective(y[0, 0], sr, col2d_mp)[None, None],
                 mesh=mesh, in_specs=P(ar, ac), out_specs=P(ar, ac),
-                check_rep=False)
+                check_vma=False)
 
             fns["load"] = jax.jit(
                 lambda parts, xs: load(vec_to_2d_layout(xs, pm.grid)))
@@ -722,7 +722,7 @@ def build_phase_fns(mesh: Mesh, pm: PartitionedMatrix, sr: Semiring,
             return xs if strategy == "row" else vec_to_2d_layout(xs, pm.grid)
 
         loader = shard_map(c_load, mesh=mesh, in_specs=spec,
-                           out_specs=(spec, spec), check_rep=False)
+                           out_specs=(spec, spec), check_vma=False)
         fns["load"] = jax.jit(lambda parts, xs: loader(pre(xs)))
         fns["kernel"] = None          # folded into e2e - load (derived)
 

@@ -46,15 +46,17 @@ class Semiring:
         ⊗ is min, which dot would silently get wrong."""
         return self.add is jnp.add and self.mul is jnp.multiply
 
-    def add_reduce(self, x: Array, axis: int | tuple[int, ...]) -> Array:
+    def add_reduce(self, x: Array, axis: int | tuple[int, ...],
+                   keepdims: bool = False) -> Array:
+        kw = {"axis": axis, "keepdims": keepdims}
         if self.collective == "psum":
-            return jnp.sum(x, axis=axis)
+            return jnp.sum(x, **kw)
         if self.collective == "pmin":
-            return jnp.min(x, axis=axis)
+            return jnp.min(x, **kw)
         if self.collective == "pmax":
-            return jnp.max(x, axis=axis)
+            return jnp.max(x, **kw)
         if self.collective == "por":
-            return jnp.any(x, axis=axis) if x.dtype == jnp.bool_ else jnp.max(x, axis=axis)
+            return jnp.any(x, **kw) if x.dtype == jnp.bool_ else jnp.max(x, **kw)
         raise ValueError(self.collective)
 
     def segment_reduce(self, data: Array, segment_ids: Array, num_segments: int) -> Array:

@@ -135,7 +135,7 @@ def lower_triangle(g: Graph):
 
 
 def triangle_problem(g: Graph, impl: str = "csr",
-                     block: tuple[int, int] = (64, 64)):
+                     block: tuple[int, int] = (128, 128)):
     """Host-side build (the paper's untimed matrix-load phase): returns
     ``(a, b, mask, impl_kw)`` ready for spgemm_masked — L in the container
     ``impl`` selects, Lᵀ dense, and L itself as the structural mask."""
@@ -165,7 +165,7 @@ def triangle_problem(g: Graph, impl: str = "csr",
 
 
 def triangle_count(g: Graph, impl: str = "csr",
-                   block: tuple[int, int] = (64, 64)) -> TriangleResult:
+                   block: tuple[int, int] = (128, 128)) -> TriangleResult:
     """Masked SpGEMM triangle count: C[i,j] = |{k : k<j<i, (i,k),(j,k)∈E}|
     for every edge (i,j) of L, so ΣC counts each triangle (k<j<i) once.
     ``impl`` picks L's container: "csr" (element path), "bsr"/"bsr_ref"

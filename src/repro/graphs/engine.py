@@ -6,10 +6,17 @@ returning *dense* vectors — the SpMSpV branch compresses internally. This
 keeps `lax.cond` signatures uniform and lets the same app code run on a
 single device (element or Pallas kernels) or on a mesh (distributed
 closures built from core.distributed).
+
+The engine keeps its matrices as one pytree (``mats``) next to the
+closures built over them. ``bind(mats)`` rebuilds the closures over other
+arrays of the same structure — traced jit arguments, in the served
+runners of graphs/multi.py — so a compiled program takes the graph as
+parameters instead of embedding it as constants.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable
 
 import jax
@@ -45,6 +52,17 @@ class GraphEngine:
     sr: Semiring
     spmv_batch_fn: MatvecFn | None = None
     spmspv_batch_fn: MatvecFn | None = None
+    mats: tuple = ()       # (SpMV matrix, SpMSpV matrix) pytree
+    make_fns: Callable[..., tuple] | None = None   # mats -> the 4 closures
+
+    def bind(self, mats) -> "GraphEngine":
+        """This engine with its closures rebuilt over ``mats`` (same tree
+        structure as ``self.mats``, e.g. traced jit arguments)."""
+        spmv_fn, spmspv_fn, spmv_batch_fn, spmspv_batch_fn = \
+            self.make_fns(*mats)
+        return dataclasses.replace(
+            self, mats=mats, spmv_fn=spmv_fn, spmspv_fn=spmspv_fn,
+            spmv_batch_fn=spmv_batch_fn, spmspv_batch_fn=spmspv_batch_fn)
 
     def adaptive_fn(self, x: Array, density: Array) -> Array:
         """One adaptive matvec: SpMV above the density threshold else SpMSpV."""
@@ -147,11 +165,6 @@ def build_engine(g: Graph, sr: Semiring, stump: DecisionStump | None = None,
     a_mv = build(fmt_spmv)
     a_msv = build(fmt_spmspv)
     n_pad = max(getattr(a_mv, "shape", shape)[0], getattr(a_msv, "shape", shape)[0])
-
-    def spmv_fn(x: Array) -> Array:
-        xp = _pad(x, a_mv.shape[1], sr)
-        return _pad(spmv(a_mv, xp, sr)[: shape[0]], n_pad, sr)
-
     # Bucketed frontiers (TPU adaptation, DESIGN.md §2): XLA needs static
     # shapes, so a single f_max=n frontier would make SpMSpV's work
     # density-independent — the opposite of the paper's point. Instead we
@@ -162,6 +175,35 @@ def build_engine(g: Graph, sr: Semiring, stump: DecisionStump | None = None,
         buckets = [min(f_max, g.n)]
     else:
         buckets = sorted({max(64, g.n // 16), max(128, g.n // 4), g.n})
+    make_fns = functools.partial(_make_fns, shape=shape, n_pad=n_pad, sr=sr,
+                                 buckets=tuple(buckets), graph_nnz=g.nnz)
+    spmv_fn, spmspv_fn, spmv_batch_fn, spmspv_batch_fn = make_fns(a_mv, a_msv)
+    feats = g.features()
+    return GraphEngine(
+        spmv_fn=spmv_fn,
+        spmspv_fn=spmspv_fn,
+        n=n_pad,
+        n_true=g.n,
+        threshold=stump.switch_threshold(feats),
+        graph_class=stump.classify(feats),
+        sr=sr,
+        spmv_batch_fn=spmv_batch_fn,
+        spmspv_batch_fn=spmspv_batch_fn,
+        mats=(a_mv, a_msv),
+        make_fns=make_fns,
+    )
+
+
+def _make_fns(a_mv, a_msv, *, shape, n_pad: int, sr: Semiring,
+              buckets: tuple, graph_nnz: int):
+    """The engine's four matvec closures over (a_mv, a_msv): single-vector
+    SpMV / bucketed SpMSpV and their [B, n]-block counterparts. Reads only
+    static structure (shapes, formats, max_col_nnz) from the matrices'
+    pytree metadata, so traced matrices work as well as concrete ones."""
+
+    def spmv_fn(x: Array) -> Array:
+        xp = _pad(x, a_mv.shape[1], sr)
+        return _pad(spmv(a_mv, xp, sr)[: shape[0]], n_pad, sr)
 
     def msv_at(fmax):
         def fn(x: Array) -> Array:
@@ -180,7 +222,6 @@ def build_engine(g: Graph, sr: Semiring, stump: DecisionStump | None = None,
         sel = jnp.minimum(sel, len(buckets) - 1)
         return jax.lax.switch(sel, branches, x)
 
-    feats = g.features()
     # Batched closures. The SpMSpV bucket ladder survives batching as a
     # *scalar* switch: the selected rung's capacity covers every row, so
     # each row's result is the same (lossless) vector the unbatched ladder
@@ -208,7 +249,7 @@ def build_engine(g: Graph, sr: Semiring, stump: DecisionStump | None = None,
         # *identical* vector for strictly less work. Union frontiers densify
         # B times faster than single ones, so batched ladders cross over on
         # rungs single-source traversals still run sparse.
-        if (fmax * a_msv.max_col_nnz >= g.nnz
+        if (fmax * a_msv.max_col_nnz >= graph_nnz
                 and isinstance(a_mv, (formats.COOMatrix, formats.CSRMatrix))):
             return spmv_batch_fn
 
@@ -230,17 +271,7 @@ def build_engine(g: Graph, sr: Semiring, stump: DecisionStump | None = None,
         sel = jnp.searchsorted(jnp.asarray(buckets, jnp.int32), nnz)
         sel = jnp.minimum(sel, len(batch_branches) - 1)
         return jax.lax.switch(sel, batch_branches, xs)
-    return GraphEngine(
-        spmv_fn=spmv_fn,
-        spmspv_fn=spmspv_fn,
-        n=n_pad,
-        n_true=g.n,
-        threshold=stump.switch_threshold(feats),
-        graph_class=stump.classify(feats),
-        sr=sr,
-        spmv_batch_fn=spmv_batch_fn,
-        spmspv_batch_fn=spmspv_batch_fn,
-    )
+    return spmv_fn, spmspv_fn, spmv_batch_fn, spmspv_batch_fn
 
 
 def calibrate_threshold(engine: GraphEngine, probe_densities=(0.01, 0.05,

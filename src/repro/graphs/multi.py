@@ -14,6 +14,10 @@ kernel-choice trace and per-query iteration counts).
 independent, so the block row-shards with no cross-device traffic beyond
 the scalar convergence reduction.
 
+Every runner takes the engine's matrices as jit arguments
+(:class:`BatchRunner`): the compiled program's size does not grow with the
+graph, and one program serves any graph of the same shapes.
+
 ``traverse_multi_buckets`` is the pipelined bucket mode: several source
 buckets drain through core.pipeline.pipeline_buckets so bucket *t+1*'s
 jitted while_loop is dispatched while bucket *t*'s results are awaited —
@@ -82,6 +86,27 @@ def _masked_trace_update(trace: Array, it: Array, active: Array,
     return trace.at[:, it].set(jnp.where(active, value, trace[:, it]))
 
 
+class BatchRunner:
+    """A batched traversal compiled with the graph as an argument.
+
+    ``jitted(mats, *args)`` is the jitted program: ``body(engine, *args)``
+    traced against ``engine.bind(mats)``, so the engine's matrices enter as
+    parameters, never as constants. Calling the runner feeds it the
+    engine's own matrices — replicated over ``mesh`` once, here, when the
+    query block is sharded."""
+
+    def __init__(self, engine: GraphEngine, body: Callable,
+                 mesh: Mesh | None = None):
+        self.mats = engine.mats
+        if mesh is not None:
+            self.mats = jax.device_put(self.mats, NamedSharding(mesh, P()))
+        self.jitted = jax.jit(
+            lambda mats, *args: body(engine.bind(mats), *args))
+
+    def __call__(self, *args):
+        return self.jitted(self.mats, *args)
+
+
 def make_bfs_multi(engine: GraphEngine, batch: int, max_iters: int = 64,
                    policy: str = "adaptive", mesh: Mesh | None = None,
                    axis_name: str = "batch"
@@ -90,9 +115,9 @@ def make_bfs_multi(engine: GraphEngine, batch: int, max_iters: int = 64,
     sr = engine.sr
     assert sr.name == BOOL_OR_AND.name
     n, b = engine.n, batch
-    step = engine.batch_step_fn(policy)
 
-    def run(sources: Array) -> BFSBatchResult:
+    def run(engine: GraphEngine, sources: Array) -> BFSBatchResult:
+        step = engine.batch_step_fn(policy)
         rows = jnp.arange(b)
         frontier = jnp.zeros((b, n), sr.dtype).at[rows, sources].set(1)
         visited = jnp.zeros((b, n), jnp.int32).at[rows, sources].set(1)
@@ -131,10 +156,10 @@ def make_bfs_multi(engine: GraphEngine, batch: int, max_iters: int = 64,
             cond, body, state0)
         return BFSBatchResult(levels[:, : engine.n_true], iters, dens, kern)
 
-    return jax.jit(run)
+    return BatchRunner(engine, run, mesh)
 
 
-def _relax_block(engine: GraphEngine, step, policy: str, max_iters: int,
+def _relax_block(engine: GraphEngine, policy: str, max_iters: int,
                  dist: Array, changed: Array) -> SSSPBatchResult:
     """The ⟨min,+⟩ re-relaxation loop over a [B, n] state block, shared by
     the cold-start SSSP runner and the warm-start resume runner: relax
@@ -145,6 +170,7 @@ def _relax_block(engine: GraphEngine, step, policy: str, max_iters: int,
     recompute is built on."""
     sr = engine.sr
     b = dist.shape[0]
+    step = engine.batch_step_fn(policy)
 
     def cond(state):
         _di, _ch, it, done, _its, _d, _k = state
@@ -186,18 +212,17 @@ def make_sssp_multi(engine: GraphEngine, batch: int, max_iters: int = 64,
     sr = engine.sr
     assert sr.name == MIN_PLUS.name
     n, b = engine.n, batch
-    step = engine.batch_step_fn(policy)
 
-    def run(sources: Array) -> SSSPBatchResult:
+    def run(engine: GraphEngine, sources: Array) -> SSSPBatchResult:
         rows = jnp.arange(b)
         dist = jnp.full((b, n), jnp.inf, jnp.float32).at[rows, sources].set(0.0)
         changed = jnp.full((b, n), jnp.inf, jnp.float32
                            ).at[rows, sources].set(0.0)
         dist = _constrain_block(dist, mesh, axis_name)
         changed = _constrain_block(changed, mesh, axis_name)
-        return _relax_block(engine, step, policy, max_iters, dist, changed)
+        return _relax_block(engine, policy, max_iters, dist, changed)
 
-    return jax.jit(run)
+    return BatchRunner(engine, run, mesh)
 
 
 def make_relax_multi(engine: GraphEngine, batch: int, max_iters: int = 64,
@@ -214,17 +239,17 @@ def make_relax_multi(engine: GraphEngine, batch: int, max_iters: int = 64,
     sr = engine.sr
     assert sr.name == MIN_PLUS.name
     n = engine.n
-    step = engine.batch_step_fn(policy)
 
-    def run(dist0: Array, changed0: Array) -> SSSPBatchResult:
+    def run(engine: GraphEngine, dist0: Array,
+            changed0: Array) -> SSSPBatchResult:
         pad = ((0, 0), (0, n - dist0.shape[1]))
         dist = jnp.pad(dist0, pad, constant_values=jnp.inf)
         changed = jnp.pad(changed0, pad, constant_values=jnp.inf)
         dist = _constrain_block(dist, mesh, axis_name)
         changed = _constrain_block(changed, mesh, axis_name)
-        return _relax_block(engine, step, policy, max_iters, dist, changed)
+        return _relax_block(engine, policy, max_iters, dist, changed)
 
-    return jax.jit(run)
+    return BatchRunner(engine, run, mesh)
 
 
 def make_ppr_multi(engine: GraphEngine, batch: int, alpha: float = 0.85,
@@ -236,9 +261,9 @@ def make_ppr_multi(engine: GraphEngine, batch: int, alpha: float = 0.85,
     sr = engine.sr
     assert sr.name == PLUS_TIMES.name
     n, b = engine.n, batch
-    step = engine.batch_step_fn(policy)
 
-    def run(sources: Array) -> PPRBatchResult:
+    def run(engine: GraphEngine, sources: Array) -> PPRBatchResult:
+        step = engine.batch_step_fn(policy)
         rows = jnp.arange(b)
         e_s = jnp.zeros((b, n), jnp.float32).at[rows, sources].set(1.0)
         e_s = _constrain_block(e_s, mesh, axis_name)
@@ -270,7 +295,7 @@ def make_ppr_multi(engine: GraphEngine, batch: int, alpha: float = 0.85,
         r, _it, res, iters, dens, kern = jax.lax.while_loop(cond, body, state0)
         return PPRBatchResult(r[:, : engine.n_true], iters, dens, kern, res)
 
-    return jax.jit(run)
+    return BatchRunner(engine, run, mesh)
 
 
 _MAKERS = {"bfs": make_bfs_multi, "sssp": make_sssp_multi,

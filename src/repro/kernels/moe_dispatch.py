@@ -35,7 +35,7 @@ def _kernel(tok_ref, x_ref, out_ref, *, n_tokens: int):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def moe_dispatch_gather(x, slot_tok, *, block_d: int = 128,
-                        interpret: bool = True):
+                        interpret: bool):
     """out[s] = x[slot_tok[s]] (zero row for padded slots)."""
     t, d = x.shape
     (s,) = slot_tok.shape

@@ -1,8 +1,11 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``INTERPRET`` defaults to True off-TPU so the whole suite (tests, CPU
-benches, distributed engine) runs the *kernel body* in interpret mode;
-on a real TPU backend it compiles to Mosaic.
+This module is the one place that decides interpret mode: every wrapper
+takes ``interpret=None`` and resolves it through :func:`interpret_mode`,
+which is True off-TPU (tests, CPU benches and the distributed engine run
+the *kernel body* in the Pallas interpreter) and False on a TPU backend,
+where the kernels compile to Mosaic. The kernel entry points themselves
+have no default, so nothing below this layer picks a mode silently.
 """
 from __future__ import annotations
 
@@ -25,14 +28,21 @@ from repro.kernels.spmspv_tiles import (
 
 Array = jax.Array
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def interpret_mode(interpret: bool | None = None) -> bool:
+    """An explicit choice wins; otherwise interpret exactly when the
+    default backend is not a TPU. Asked at call time, not at import, so
+    importing the kernels never initialises a backend."""
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() != "tpu"
 
 
 def semiring_spmv(a: PaddedBSR, x: Array, sr: Semiring,
                   interpret: bool | None = None) -> Array:
     """y = A ⊕.⊗ x (dense x). x length must be a.shape[1] (padded)."""
     assert x.shape[0] == a.shape[1], (x.shape, a.shape)
-    itp = INTERPRET if interpret is None else interpret
+    itp = interpret_mode(interpret)
     return semiring_spmv_padded(a.tiles, a.tile_cols, x.astype(sr.dtype),
                                 sr=sr, interpret=itp)
 
@@ -60,7 +70,7 @@ def semiring_spmv_fused(a: PaddedBSR, x: Array, sr: Semiring,
     Bit-identical to semiring_spmv; with ``chunks=d`` the output comes back
     chunk-major [d, m/d] for collectives.merge_chunks."""
     assert x.shape[0] == a.shape[1], (x.shape, a.shape)
-    itp = INTERPRET if interpret is None else interpret
+    itp = interpret_mode(interpret)
     return semiring_spmv_fused_padded(a.tiles, _spmv_fused_meta(a),
                                       x.astype(sr.dtype), sr=sr,
                                       interpret=itp, chunks=chunks)
@@ -71,7 +81,7 @@ def semiring_spmv_sliced(s: SlicedELL, x: Array, sr: Semiring,
                          chunks: int | None = None) -> Array:
     """Fused SpMV over the sell-C-σ layout (hub-skew pad collapse)."""
     assert x.shape[0] == s.shape[1], (x.shape, s.shape)
-    itp = INTERPRET if interpret is None else interpret
+    itp = interpret_mode(interpret)
     return semiring_spmv_sell(s.tiles, s.tile_cols, s.row_meta,
                               x.astype(sr.dtype), sr=sr, interpret=itp,
                               chunks=chunks)
@@ -102,7 +112,7 @@ def semiring_spmspv(a: PaddedBSR, f: Frontier, sr: Semiring,
     """y = A ⊕.⊗ x with x given as a sparse Frontier. Only active column
     tiles are streamed (the paper's CSC-SpMSpV work-skipping, at tile
     granularity)."""
-    itp = INTERPRET if interpret is None else interpret
+    itp = interpret_mode(interpret)
     meta = _spmspv_meta(a, f, sr)
     x_dense = f.to_dense(sr)
     pad = a.shape[1] - x_dense.shape[0]
@@ -116,7 +126,7 @@ def semiring_spmspv_fused(a: PaddedBSR, f: Frontier, sr: Semiring,
                           chunks: int | None = None) -> Array:
     """Fused Load+Kernel SpMSpV: only frontier-active slots are DMA'd
     through the double-buffered scratch. Bit-identical to semiring_spmspv."""
-    itp = INTERPRET if interpret is None else interpret
+    itp = interpret_mode(interpret)
     meta = _spmspv_meta(a, f, sr)
     x_dense = f.to_dense(sr)
     pad = a.shape[1] - x_dense.shape[0]
@@ -249,7 +259,7 @@ def semiring_spgemm(a: PaddedBSR, b: Array, sr: Semiring,
                     interpret: bool | None = None) -> Array:
     """C = (A ⊕.⊗ B) ⊙ mask. A in ELL-of-tiles; B dense [a.shape[1], N];
     mask dense [a.shape[0], N] or None. Output [a.shape[0], N]."""
-    itp = INTERPRET if interpret is None else interpret
+    itp = interpret_mode(interpret)
     bp, mk, meta, bn, n = _spgemm_operands(a, b, sr, mask)
     c = semiring_spgemm_padded(a.tiles, meta, bp, mk, sr=sr, bn=bn,
                                interpret=itp)
@@ -267,7 +277,7 @@ def moe_dispatch_gather(x: Array, slot_tok: Array, block_d: int = 128,
     """Expert-buffer row gather (tile-SpMSpV analogue; DESIGN.md §5):
     out[s] = x[slot_tok[s]], zero rows for padded slots."""
     from repro.kernels.moe_dispatch import moe_dispatch_gather as _k
-    itp = INTERPRET if interpret is None else interpret
+    itp = interpret_mode(interpret)
     return _k(x, slot_tok, block_d=block_d, interpret=itp)
 
 

@@ -65,7 +65,7 @@ def _kernel(meta_ref, tiles_ref, b_ref, mask_ref, o_ref, *, sr: Semiring,
 
 @functools.partial(jax.jit, static_argnames=("sr", "bn", "interpret"))
 def semiring_spgemm_padded(tiles, meta, b, mask, *, sr: Semiring, bn: int,
-                           interpret: bool = True):
+                           interpret: bool):
     """C = (A ⊕.⊗ B) ⊙ mask over the padded ELL-of-tiles layout. ``bn`` is
     the output tile width; b/mask column counts must be bn-multiples."""
     mb, t_grid, bm, bk = tiles.shape

@@ -12,7 +12,8 @@ with no active frontier entry:
   slot instead of issuing a dead DMA — the same work-skipping UPMEM's DPU
   gets by not issuing the inactive column's DMA (§4.1.3).
 * The kernel masks compute with ``pl.when(j < n_active[i])``.
-* x enters densified ([nb*bn]); inactive x blocks are never indexed.
+* x enters densified as one [1, nb*bn] row (the same row layout as
+  semiring_spmv.py); inactive x blocks are never indexed.
 
 meta layout (scalar-prefetched, int32 [mb, 1 + 2T]):
     meta[i, 0]         = n_active_i
@@ -29,7 +30,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.semiring import Semiring
-from repro.kernels.semiring_spmv import _emit, _out_spec, _stream_row
+from repro.kernels.semiring_spmv import (
+    _row_specs, _stream_row, _tile_contrib, _unrow,
+)
 
 
 def _kernel(meta_ref, tiles_ref, x_ref, y_ref, *, sr: Semiring):
@@ -44,17 +47,12 @@ def _kernel(meta_ref, tiles_ref, x_ref, y_ref, *, sr: Semiring):
 
     @pl.when(j < n_active)
     def _compute():
-        a = tiles_ref[0, 0]
-        xb = x_ref[...]
-        if sr.mxu_eligible:
-            contrib = jnp.dot(a, xb, preferred_element_type=jnp.float32).astype(y_ref.dtype)
-        else:
-            contrib = sr.add_reduce(sr.mul(a, xb[None, :]), axis=1)
+        contrib = _tile_contrib(tiles_ref[0, 0], x_ref[...], sr, y_ref.dtype)
         y_ref[...] = sr.add(y_ref[...], contrib)
 
 
 @functools.partial(jax.jit, static_argnames=("sr", "interpret"))
-def semiring_spmspv_padded(tiles, meta, x, *, sr: Semiring, interpret: bool = True):
+def semiring_spmspv_padded(tiles, meta, x, *, sr: Semiring, interpret: bool):
     """tiles [mb, T, bm, bn] (unpermuted ELL-of-tiles); meta as above;
     x densified [nb*bn]."""
     mb, t_grid, bm, bn = tiles.shape
@@ -66,37 +64,37 @@ def semiring_spmspv_padded(tiles, meta, x, *, sr: Semiring, interpret: bool = Tr
 
     def _x_map(i, j, meta):
         ok = j < meta[i, 0]
-        return (jnp.where(ok, meta[i, 1 + t_grid + j], meta[i, 1 + t_grid]),)
+        return (0, jnp.where(ok, meta[i, 1 + t_grid + j], meta[i, 1 + t_grid]))
 
-    return pl.pallas_call(
+    y = pl.pallas_call(
         functools.partial(_kernel, sr=sr),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(mb, t_grid),
             in_specs=[
                 pl.BlockSpec((1, 1, bm, bn), _tile_map),
-                pl.BlockSpec((bn,), _x_map),
+                pl.BlockSpec((1, bn), _x_map),
             ],
-            out_specs=pl.BlockSpec((bm,), lambda i, j, meta: (i,)),
+            out_specs=pl.BlockSpec((1, bm), lambda i, j, meta: (0, i)),
         ),
-        out_shape=jax.ShapeDtypeStruct((mb * bm,), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((1, mb * bm), x.dtype),
         interpret=interpret,
-    )(meta, tiles, x)
+    )(meta, tiles, x.reshape(1, -1))
+    return y.reshape(-1)
 
 
 def _fused_kernel(meta_ref, tiles_ref, x_ref, y_ref, *, sr: Semiring,
-                  bm: int, bn: int, t_grid: int, dtype, chunked: bool):
+                  bm: int, bn: int, t_grid: int, dtype):
     i = pl.program_id(0)
     n_active = meta_ref[i, 0]
-    acc = _stream_row(lambda j: tiles_ref.at[i, meta_ref[i, 1 + j]],
-                      lambda j: meta_ref[i, 1 + t_grid + j],
-                      x_ref, n_active, sr=sr, bm=bm, bn=bn, dtype=dtype)
-    _emit(y_ref, acc, chunked)
+    y_ref[...] = _stream_row(lambda j: tiles_ref.at[i, meta_ref[i, 1 + j]],
+                             lambda j: meta_ref[i, 1 + t_grid + j],
+                             x_ref, n_active, sr=sr, bm=bm, bn=bn, dtype=dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("sr", "interpret", "chunks"))
 def semiring_spmspv_fused_padded(tiles, meta, x, *, sr: Semiring,
-                                 interpret: bool = True,
+                                 interpret: bool,
                                  chunks: int | None = None):
     """Fused Load+Kernel SpMSpV: same meta layout as the unfused kernel, but
     the adjacency stays in ANY/HBM and only frontier-active slots are DMA'd
@@ -104,19 +102,20 @@ def semiring_spmspv_fused_padded(tiles, meta, x, *, sr: Semiring,
     all, vs the unfused kernel's masked re-read of a resident slot).
     Bit-identical to semiring_spmspv_padded."""
     mb, t_grid, bm, bn = tiles.shape
-    out_specs, out_shape = _out_spec(mb, bm, chunks, lambda i, meta: i, x.dtype)
-    return pl.pallas_call(
+    x_spec, y_spec, out_shape = _row_specs(x, mb, bm, lambda i, meta: i)
+    y = pl.pallas_call(
         functools.partial(_fused_kernel, sr=sr, bm=bm, bn=bn, t_grid=t_grid,
-                          dtype=x.dtype, chunked=chunks is not None),
+                          dtype=x.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(mb,),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec((x.shape[0],), lambda i, meta: (0,)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                x_spec,
             ],
-            out_specs=out_specs,
+            out_specs=y_spec,
         ),
         out_shape=out_shape,
         interpret=interpret,
-    )(meta, tiles, x)
+    )(meta, tiles, x.reshape(1, -1))
+    return _unrow(y, chunks)

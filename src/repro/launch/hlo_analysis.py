@@ -328,20 +328,9 @@ def top_level_bytes(op: Op, comp: Computation,
 
 
 def normalize_cost_analysis(cost) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` has changed return shape across jax
-    releases: a dict, a list of per-device dicts (one entry per program),
-    or None. Collapse all of them to one flat {metric: value} dict (first
-    program's entry wins; metrics are per-device either way)."""
-    if cost is None:
-        return {}
-    if isinstance(cost, dict):
-        return cost
-    if isinstance(cost, (list, tuple)):
-        for entry in cost:
-            if isinstance(entry, dict):
-                return entry
-        return {}
-    return {}
+    """``compiled.cost_analysis()`` -> a flat {metric: value} dict (None,
+    for a backend that reports no costs, becomes {})."""
+    return dict(cost or {})
 
 
 @dataclasses.dataclass

@@ -954,13 +954,16 @@ class AsyncGraphServer:
 
     def close(self) -> None:
         """Stop the loop thread (if running) and drain every pending
-        window so no admitted ticket is left unresolved."""
+        window so no admitted ticket is left unresolved; then re-raise
+        the first exception a window's drain raised (those tickets were
+        already failed with it)."""
         if self._thread is not None:
             self._stop.set()
             self.scheduler.kick()
             self._thread.join()
             self._thread = None
         self.scheduler.drain()
+        self.scheduler.raise_failure()
 
     def __enter__(self) -> "AsyncGraphServer":
         return self.start()

@@ -133,7 +133,8 @@ class QueryTicket:
     __slots__ = ("tenant", "algorithm", "source", "priority", "deadline",
                  "admitted_at", "dispatched_at", "resolved_at", "seq",
                  "request_id", "window_id", "submitted_pc", "abandoned",
-                 "result", "cached", "_event", "_sched", "_timed_out")
+                 "result", "cached", "error", "_event", "_sched",
+                 "_timed_out")
 
     def __init__(self, tenant: str, algorithm: str = "", source: int = -1,
                  priority: int = 0, deadline: Optional[float] = None):
@@ -154,6 +155,8 @@ class QueryTicket:
         self.abandoned = False
         self.result: Optional[Dict[str, Any]] = None
         self.cached = False
+        # the executor's exception when this ticket's window failed
+        self.error: Optional[BaseException] = None
         self._event = threading.Event()
         self._sched: Optional["WindowScheduler"] = None
         self._timed_out = False
@@ -175,6 +178,16 @@ class QueryTicket:
         self.resolved_at = self.dispatched_at if at is None else at
         self._event.set()
         return payload
+
+    def fail(self, error: BaseException,
+             at: Optional[float] = None) -> None:
+        """Resolve with ``error`` instead of a payload: ``wait()`` raises
+        it. A ticket that already resolved keeps its payload."""
+        if self._event.is_set():
+            return
+        self.error = error
+        self.resolved_at = self.dispatched_at if at is None else at
+        self._event.set()
 
     def slack(self) -> Optional[float]:
         """Seconds of deadline margin at resolve time: positive = met,
@@ -207,6 +220,8 @@ class QueryTicket:
             raise TimeoutError(
                 f"ticket ({self.tenant}/{self.algorithm}/{self.source}) "
                 f"unresolved after {timeout}s — is the event loop running?")
+        if self.error is not None:
+            raise self.error
         assert self.result is not None
         return self.result
 
@@ -339,6 +354,8 @@ class WindowScheduler:
         self.dispatched = 0
         self.abandoned = 0
         self.depth_high_water = 0
+        # executor exceptions, oldest first (see _run / raise_failure)
+        self.failures: List[BaseException] = []
 
     # ------------------------------------------------------------- setup
     def register(self, name: str, batch_size: int = 8,
@@ -422,10 +439,22 @@ class WindowScheduler:
         return tickets
 
     def _run(self, batches: List[Tuple[str, List[QueryTicket]]]) -> int:
-        """Execute popped windows outside the lock; returns #tickets."""
+        """Execute popped windows outside the lock; returns #tickets.
+
+        An executor exception fails that window's unresolved tickets with
+        it (their ``wait()`` raises it) and is kept for
+        :meth:`raise_failure`; the other windows still run, and the
+        event loop keeps serving."""
         n = 0
         for name, tickets in batches:
-            self.executor(name, tickets)
+            try:
+                self.executor(name, tickets)
+            except Exception as e:
+                now = self.clock.now()
+                for tk in tickets:
+                    tk.fail(e, at=now)
+                with self._cond:
+                    self.failures.append(e)
             n += len(tickets)
         if n:
             with self._cond:
@@ -487,6 +516,14 @@ class WindowScheduler:
             if tenant is not None:
                 return len(self._tenants[tenant].tickets)
             return self._pending
+
+    def raise_failure(self) -> None:
+        """Re-raise the first executor exception not raised here yet."""
+        with self._cond:
+            if not self.failures:
+                return
+            first, self.failures = self.failures[0], []
+        raise first
 
     def kick(self) -> None:
         """Wake a blocked ``run_loop`` (shutdown, config change)."""

@@ -53,18 +53,11 @@ def compressed_psum_mean(x: Array, ef: Array, axis_name: str
     carry = x.astype(jnp.float32) + ef
     q, scale = quantize_int8(carry)
     new_ef = carry - dequantize_int8(q, scale)
-    if hasattr(jax, "shard_map"):
-        n = jax.lax.axis_size(axis_name)
-        qg = jax.lax.all_gather(q, axis_name)        # [n, ...] int8 on the wire
-        sg = jax.lax.all_gather(scale, axis_name)    # [n]
-        deq = qg.astype(jnp.float32) * sg.reshape((n,) + (1,) * x.ndim)
-        return jnp.sum(deq, axis=0) / n, new_ef
-    # jax < 0.5 compat: the partial-manual shard_map CHECK-crashes XLA's SPMD
-    # partitioner on all-gather (probed); psum of the dequantized terms is the
-    # same sum, though the wire carries f32 on this path.
-    n = jax.lax.psum(1, axis_name)
-    deq = dequantize_int8(q, scale)
-    return jax.lax.psum(deq, axis_name) / n, new_ef
+    n = jax.lax.axis_size(axis_name)
+    qg = jax.lax.all_gather(q, axis_name)        # [n, ...] int8 on the wire
+    sg = jax.lax.all_gather(scale, axis_name)    # [n]
+    deq = qg.astype(jnp.float32) * sg.reshape((n,) + (1,) * x.ndim)
+    return jnp.sum(deq, axis=0) / n, new_ef
 
 
 def compressed_tree_psum_mean(grads, ef_tree, axis_name: str):
@@ -72,25 +65,5 @@ def compressed_tree_psum_mean(grads, ef_tree, axis_name: str):
     pairs = jax.tree.map(
         lambda g, e: compressed_psum_mean(g, e, axis_name), grads, ef_tree)
     outer = jax.tree.structure(grads)
-    inner = jax.tree.structure((0, 0))
-    return jax.tree.transpose(outer, inner, pairs)
-
-
-def compressed_stacked_mean(g_stack: Array, ef: Array) -> Tuple[Array, Array]:
-    """Pod-stacked ([P, ...]) counterpart of compressed_psum_mean for the
-    pure-pjit fallback (jax < 0.5, where partial-manual shard_map is
-    unsupported): per-pod int8 quantization against a shared error-feedback
-    buffer, mean over the leading pod axis."""
-    carry = g_stack.astype(jnp.float32) + ef[None]
-    q, scale = jax.vmap(quantize_int8)(carry)
-    deq = jax.vmap(dequantize_int8)(q, scale)
-    new_ef = jnp.mean(carry - deq, axis=0)
-    return jnp.mean(deq, axis=0), new_ef
-
-
-def compressed_tree_stacked_mean(grads_stack, ef_tree):
-    """Leaf-wise compressed_stacked_mean over a pod-stacked gradient pytree."""
-    pairs = jax.tree.map(compressed_stacked_mean, grads_stack, ef_tree)
-    outer = jax.tree.structure(ef_tree)
     inner = jax.tree.structure((0, 0))
     return jax.tree.transpose(outer, inner, pairs)

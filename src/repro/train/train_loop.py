@@ -34,17 +34,10 @@ Array = jax.Array
 
 def partial_shard_map(body, mesh: Mesh, manual_axes, in_specs, out_specs):
     """shard_map that is manual only over ``manual_axes``; the remaining mesh
-    axes stay automatic (SPMD-partitioned). jax >= 0.6 spells this
-    ``jax.shard_map(axis_names=...)``; older releases only ship
-    ``jax.experimental.shard_map.shard_map(auto=...)``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, axis_names=set(manual_axes),
-                             check_vma=False, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False, auto=auto)
+    axes stay automatic (SPMD-partitioned)."""
+    return jax.shard_map(body, mesh=mesh, axis_names=set(manual_axes),
+                         check_vma=False, in_specs=in_specs,
+                         out_specs=out_specs)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,42 +143,6 @@ def make_compressed_train_step(model: Model, mesh: Mesh, cfg: TrainConfig):
         nu=zero1_shardings(mesh, specs),
     )
     ef_sh = zero1_shardings(mesh, specs)
-
-    if not hasattr(jax, "shard_map"):
-        # jax < 0.5 fallback: partial-manual shard_map CHECK-crashes this
-        # XLA's SPMD partitioner on any nontrivial body (probed), so per-pod
-        # gradients are expressed as a vmap over a leading pod axis under
-        # pure pjit — the [GB] -> [n_pod, GB/n_pod] batch reshape lets XLA
-        # run the vmapped grads pod-parallel, and the mean over axis 0 is
-        # the cross-pod reduction. int8+EF numerics match the manual path
-        # up to the shared (mean) error-feedback buffer.
-        from repro.train.grad_compress import compressed_tree_stacked_mean
-        n_pod = dict(mesh.shape)["pod"]
-
-        def body_vmap(params, opt_state, ef, batch):
-            from repro.distributed.sharding import (
-                activation_mesh, set_activation_mesh)
-            prev = activation_mesh()
-            set_activation_mesh(None)
-            try:
-                slices = _split_micro(batch, n_pod)
-
-                def pod_grads(mb):
-                    g, l, _ = _grads_and_loss(model, params, mb, cfg)
-                    return g, l
-
-                grads_p, loss_p = jax.vmap(pod_grads)(slices)
-            finally:
-                set_activation_mesh(prev)
-            grads, ef = compressed_tree_stacked_mean(grads_p, ef)
-            loss = jnp.mean(loss_p)
-            params, opt_state, om = adamw_apply(params, grads, opt_state,
-                                                cfg.opt)
-            return params, opt_state, ef, {"loss": loss, **om}
-
-        return jax.jit(body_vmap,
-                       in_shardings=(p_sh, opt_sh, ef_sh, None),
-                       out_shardings=(p_sh, opt_sh, ef_sh, None))
 
     def body(params, opt_state, ef, batch):
         # trace WITHOUT activation constraints: XLA's SPMD partitioner

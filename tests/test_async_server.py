@@ -219,6 +219,58 @@ def test_edf_dispatch_order():
     assert keys == sorted(keys)
 
 
+def test_executor_error_fails_its_window_only():
+    """A window whose executor raises resolves its tickets with the error
+    (wait() raises it, nothing blocks); other windows still run, and
+    raise_failure() re-raises the error once."""
+    clock = FakeClock()
+    served = []
+
+    def executor(name, tks):
+        if name == "bad":
+            raise MemoryError("device out of memory")
+        for tk in tks:
+            tk.resolve({"ok": True})
+        served.append(name)
+
+    sched = WindowScheduler(executor, clock=clock, max_pending=64)
+    sched.register("bad", batch_size=2, max_wait=1.0)
+    sched.register("good", batch_size=2, max_wait=1.0)
+    bad = [sched.submit(QueryTicket("bad", "bfs", s)) for s in range(2)]
+    good = sched.submit(QueryTicket("good", "bfs", 0))
+    clock.advance(1.0)
+    assert sched.poll() == 3
+    assert served == ["good"] and good.wait(timeout=0) == {"ok": True}
+    for tk in bad:
+        assert tk.done()
+        with pytest.raises(MemoryError, match="out of memory"):
+            tk.wait(timeout=0)
+    assert sched.stats()["dispatched"] == 3
+    with pytest.raises(MemoryError):
+        sched.raise_failure()
+    sched.raise_failure()                 # raised once, then cleared
+
+
+def test_close_reraises_flush_error(graph, monkeypatch):
+    """A flush that raises inside the serving loop fails its tickets and
+    makes close() raise, instead of leaving waiters blocked forever."""
+    clock = FakeClock()
+    srv = AsyncGraphServer(clock=clock, max_wait=0.01)
+    server = srv.add_tenant("t", graph, batch_size=4)
+
+    def broken_flush():
+        raise RuntimeError("kernel refused by the compiler")
+
+    monkeypatch.setattr(server, "flush", broken_flush)
+    tks = [srv.submit("t", "bfs", s) for s in range(4)]
+    srv.poll()
+    for tk in tks:
+        with pytest.raises(RuntimeError, match="refused"):
+            tk.wait(timeout=0)
+    with pytest.raises(RuntimeError, match="refused"):
+        srv.close()
+
+
 def test_mutate_interleaves_with_pending_window(graph):
     """Queries queued before mutate() observe the pre-mutation snapshot;
     queries after observe the new one — async matches sync exactly."""

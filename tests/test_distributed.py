@@ -21,11 +21,8 @@ n = 128
 dense_np = (rng.random((n, n)) < 0.08).astype(np.float32) * rng.integers(1, 9, (n, n))
 rows, cols = np.nonzero(dense_np)
 vals = dense_np[rows, cols].astype(np.float32)
-if hasattr(jax.sharding, "AxisType"):
-    mesh = jax.make_mesh((2, 4), ("dr", "dc"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
-else:  # jax < 0.5: make_mesh axes are Auto by default
-    mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("dr", "dc"))
 
 checked = 0
 for sr in (PLUS_TIMES, MIN_PLUS, BOOL_OR_AND):
@@ -88,7 +85,8 @@ n, B = 128, 4
 dense_np = (rng.random((n, n)) < 0.08).astype(np.float32) * rng.integers(1, 9, (n, n))
 rows, cols = np.nonzero(dense_np)
 vals = dense_np[rows, cols].astype(np.float32)
-mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("dr", "dc"))
 
 checked = 0
 for sr in (PLUS_TIMES, MIN_PLUS, BOOL_OR_AND):
@@ -148,7 +146,8 @@ from repro.graphs.datasets import rmat_graph, road_graph
 from repro.graphs.engine import edge_values
 from repro.graphs.multi import partitioned_matvec
 
-mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("dr", "dc"))
 checked = 0
 for g in (rmat_graph(700, 5000, skew=0.6, seed=2),
           road_graph(900, 2.6, seed=2)):
@@ -202,7 +201,8 @@ n = 128
 dense_np = (rng.random((n, n)) < 0.08).astype(np.float32) * rng.integers(1, 9, (n, n))
 rows, cols = np.nonzero(dense_np)
 vals = dense_np[rows, cols].astype(np.float32)
-mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("dr", "dc"))
 
 checked = 0
 for sr in (PLUS_TIMES, MIN_PLUS, BOOL_OR_AND):
@@ -278,7 +278,8 @@ n = 128
 dense_np = (rng.random((n, n)) < 0.08).astype(np.float32) * rng.integers(1, 9, (n, n))
 rows, cols = np.nonzero(dense_np)
 vals = dense_np[rows, cols].astype(np.float32)
-mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("dr", "dc"))
 
 checked = 0
 for sr in (PLUS_TIMES, MIN_PLUS, PLUS_AND):
@@ -350,7 +351,8 @@ n = 192    # divisible by 12 for the col strategy's flat-axis chunks
 dense_np = (rng.random((n, n)) < 0.06).astype(np.float32) * rng.integers(1, 9, (n, n))
 rows, cols = np.nonzero(dense_np)
 vals = dense_np[rows, cols].astype(np.float32)
-mesh = jax.make_mesh((4, 3), ("dr", "dc"))   # dc=3: odd-radix merge axis
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 3), ("dr", "dc"))   # dc=3: odd-radix merge axis
 
 checked = 0
 for sr in (PLUS_TIMES, MIN_PLUS):
@@ -418,7 +420,8 @@ n = 128
 dense_np = (rng.random((n, n)) < 0.08).astype(np.float32) * rng.integers(1, 9, (n, n))
 rows, cols = np.nonzero(dense_np)
 vals = dense_np[rows, cols].astype(np.float32)
-mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("dr", "dc"))
 
 checked = 0
 for sr in (PLUS_TIMES, MIN_PLUS, BOOL_OR_AND):

@@ -98,14 +98,15 @@ model = build_model(cfg)
 params = model.init(jax.random.PRNGKey(0))
 path = sys.argv[1]
 
-mesh_a = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh_a = make_mesh((2, 2), ("data", "model"))
 sh_a = param_shardings(mesh_a, model.specs())
 params_a = jax.tree.map(jax.device_put, params, sh_a)
 ckpt.save(path, 1, {"params": params_a})
 
 # elastic rescale: restore the (2,2) checkpoint onto a (4,1)... and (1,8) mesh
 for shape in [(4, 1), (1, 8)]:
-    mesh_b = jax.make_mesh(shape, ("data", "model"))
+    mesh_b = make_mesh(shape, ("data", "model"))
     sh_b = param_shardings(mesh_b, model.specs())
     got, _ = ckpt.restore(path, 1, {"params": params}, {"params": sh_b})
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(got["params"])):

@@ -20,9 +20,8 @@ import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch import hlo_analysis as H
 
-from jax.experimental.shard_map import shard_map
-
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 L, B, D = 8, 16, 256
 W = jax.ShapeDtypeStruct((L, D, D), jnp.bfloat16)   # cols model-sharded
 X = jax.ShapeDtypeStruct((B, D), jnp.bfloat16)      # rows data-sharded
@@ -37,9 +36,9 @@ def f(ws, x):
     acc, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), ws)
     return jax.lax.psum(acc, ("data", "model"))
 
-fn = shard_map(f, mesh=mesh,
-               in_specs=(P(None, None, "model"), P("data", None)),
-               out_specs=P(), check_rep=False)
+fn = jax.shard_map(f, mesh=mesh,
+                   in_specs=(P(None, None, "model"), P("data", None)),
+                   out_specs=P(), check_vma=False)
 co = jax.jit(fn).lower(W, X).compile()
 ana = H.analyze(co.as_text(), 8, pod_size=256)
 # per-device dot flops: L * 2 * (B/2) * D * (D/4)
@@ -51,7 +50,7 @@ terms = H.roofline_terms(ana)
 assert terms["compute_s"] > 0 and terms["dominant"] in ("compute", "memory", "collective")
 
 # multi-pod mesh: the pod-axis collective must be classified as DCN
-mesh2 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh2 = make_mesh((2, 2, 2), ("pod", "data", "model"))
 def g(x):
     return x.sum()
 co2 = jax.jit(g, in_shardings=(

@@ -171,3 +171,38 @@ def test_batched_closures_match_unbatched(stump):
         np.testing.assert_allclose(ys_msv[i],
                                    np.asarray(eng.spmspv_fn(xs_j[i])),
                                    rtol=1e-6)
+
+
+def _ring_graph(n: int, k: int):
+    """Vertex i -> i+1..i+k (mod n): every column holds exactly k entries,
+    so two rings differing only in k build the same rung ladder."""
+    from repro.graphs.datasets import Graph
+
+    src = np.repeat(np.arange(n), k)
+    dst = (src + np.tile(np.arange(1, k + 1), n)) % n
+    return Graph(src.astype(np.int32), dst.astype(np.int32), n, f"ring{k}")
+
+
+def test_bfs_runner_takes_graph_as_arguments():
+    """The served runner lowers the engine's matrices as parameters of
+    ``main``, not as constants: graphs whose nnz differs by 8x lower to
+    programs of (almost) the same size."""
+    from repro.graphs.multi import make_bfs_multi
+
+    sizes = []
+    for k in (2, 16):
+        eng = build_engine(_ring_graph(4096, k), BOOL_OR_AND)
+        run = make_bfs_multi(eng, batch=B)
+        lowered = run.jitted.lower(run.mats, np.zeros(B, np.int32))
+        text = lowered.as_text()
+        main = next(ln for ln in text.splitlines() if "@main(" in ln)
+        # every per-entry array the traversal reads (CSR cols/vals/seg ids,
+        # CSC rows/vals) is a parameter; unread leaves are pruned
+        nnz_max = eng.mats[0].nnz_max
+        assert main.count(f"tensor<{nnz_max}x") >= 4, main
+        sizes.append(len(text))
+        res = run(np.arange(B, dtype=np.int32))
+        ref = bfs_multi(eng, list(range(B)))
+        np.testing.assert_array_equal(np.asarray(res.levels),
+                                      np.asarray(ref.levels))
+    assert abs(sizes[1] - sizes[0]) < 0.01 * sizes[0], sizes

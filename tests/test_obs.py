@@ -414,7 +414,8 @@ rows, cols = np.nonzero(dense)
 vals = np.ones(len(rows), np.int32)
 sr = BOOL_OR_AND
 x = (rng.random(n) < 0.05).astype(np.int32)
-mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("dr", "dc"))
 
 checked = 0
 for strategy, grid, fmt, kern, topology in [

@@ -119,11 +119,8 @@ n, nrhs = 128, 24
 dense_np = (rng.random((n, n)) < 0.08).astype(np.float32) * rng.integers(1, 9, (n, n))
 rows, cols = np.nonzero(dense_np)
 vals = dense_np[rows, cols].astype(np.float32)
-if hasattr(jax.sharding, "AxisType"):
-    mesh = jax.make_mesh((2, 4), ("dr", "dc"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
-else:
-    mesh = jax.make_mesh((2, 4), ("dr", "dc"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("dr", "dc"))
 
 checked = 0
 for sr in (PLUS_TIMES, MIN_PLUS, BOOL_OR_AND, PLUS_AND):
