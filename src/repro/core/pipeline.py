@@ -173,33 +173,22 @@ def pipeline_buckets(issue: Callable[[Any], Any],
     results: list = []
     pending: deque[tuple[Any, Any]] = deque()
     limit = max(0, depth)
-    t = trace.active()
-    if t is None:                       # hot path: zero tracing overhead
-        for item in items:
-            pending.append((item, issue(item)))
-            while len(pending) > limit:
-                it, handle = pending.popleft()
-                results.append(materialize(it, handle))
-        while pending:
-            it, handle = pending.popleft()
-            results.append(materialize(it, handle))
-        return results
-
-    # Traced: the issue window (dispatch) and the materialize window (the
-    # host sync the pipeline hides) become spans, indexed by bucket.
+    # the issue window (dispatch) and the materialize window (the host
+    # sync the pipeline hides) are spans, indexed by bucket; both are
+    # the shared no-op while nothing observes them
     n_issued = 0
     for item in items:
-        with t.span("pipeline/issue", bucket=n_issued, depth=limit):
+        with trace.span("pipeline/issue", bucket=n_issued, depth=limit):
             pending.append((item, issue(item)))
         n_issued += 1
         while len(pending) > limit:
             it, handle = pending.popleft()
-            with t.span("pipeline/materialize",
-                        bucket=n_issued - len(pending) - 1, depth=limit):
+            with trace.span("pipeline/materialize",
+                            bucket=n_issued - len(pending) - 1, depth=limit):
                 results.append(materialize(it, handle))
     while pending:
         it, handle = pending.popleft()
-        with t.span("pipeline/materialize",
-                    bucket=n_issued - len(pending) - 1, depth=limit):
+        with trace.span("pipeline/materialize",
+                        bucket=n_issued - len(pending) - 1, depth=limit):
             results.append(materialize(it, handle))
     return results

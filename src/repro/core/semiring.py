@@ -60,17 +60,23 @@ class Semiring:
         raise ValueError(self.collective)
 
     def segment_reduce(self, data: Array, segment_ids: Array, num_segments: int) -> Array:
-        """⊕-reduce ``data`` into ``num_segments`` buckets (CSR/COO kernels)."""
-        if self.collective == "psum":
-            return jax.ops.segment_sum(data, segment_ids, num_segments)
-        if self.collective in ("pmin",):
-            # empty segments come back +inf == min_plus zero, already correct
-            return jax.ops.segment_min(data, segment_ids, num_segments)
-        if self.collective in ("pmax", "por"):
-            # empty segments come back dtype-min; clamp to the ⊕-identity
-            out = jax.ops.segment_max(data, segment_ids, num_segments)
-            return jnp.maximum(out, jnp.asarray(self.zero, out.dtype))
-        raise ValueError(self.collective)
+        """⊕-reduce ``data`` into ``num_segments`` buckets (CSR/COO kernels).
+
+        Every ⊕-scatter of the served matvecs runs here, under the
+        ``segment_reduce`` name scope, so the device trace attributes its
+        ops to one stable name; another kernel doing this reduction keeps
+        the scope."""
+        with jax.named_scope("segment_reduce"):
+            if self.collective == "psum":
+                return jax.ops.segment_sum(data, segment_ids, num_segments)
+            if self.collective in ("pmin",):
+                # empty segments come back +inf == min_plus zero, already correct
+                return jax.ops.segment_min(data, segment_ids, num_segments)
+            if self.collective in ("pmax", "por"):
+                # empty segments come back dtype-min; clamp to the ⊕-identity
+                out = jax.ops.segment_max(data, segment_ids, num_segments)
+                return jnp.maximum(out, jnp.asarray(self.zero, out.dtype))
+            raise ValueError(self.collective)
 
     def preduce(self, x: Array, axis_name: str) -> Array:
         """Distributed ⊕-reduction over a mesh axis (the paper's Merge phase,

@@ -155,33 +155,39 @@ def spmspv_batch_union(a: CSCMatrix, xs: Array, sr: Semiring,
     result equals spmspv(a, frontier(xs[b])) whenever ``f_max`` covers the
     union (⊕-reduction order may differ, which matters only below float
     tolerance for ⟨+,×⟩). Work is O(f_union · max_col_nnz · B) products but
-    the expensive gather/scatter structure is batch-invariant."""
-    m, n = a.shape
-    b = xs.shape[0]
-    f_max = f_max or n
-    nz_any = jnp.any(xs != sr.zero, axis=0)                     # [n]
-    count = jnp.sum(nz_any.astype(jnp.int32))
-    order = jnp.argsort(~nz_any, stable=True)
-    idx = jnp.where(jnp.arange(n) < count, order, n)[:f_max].astype(jnp.int32)
-    ok_col = idx < n
-    safe_j = jnp.where(ok_col, idx, 0)
-    start = a.col_ptr[safe_j]                                   # [F]
-    length = a.col_ptr[safe_j + 1] - start
-    offs = jnp.arange(a.max_col_nnz, dtype=jnp.int32)           # [L]
-    gidx = start[:, None] + offs[None, :]                       # [F, L]
-    in_col = offs[None, :] < length[:, None]
-    gidx = jnp.where(in_col, gidx, a.nnz_max - 1)
-    rows = a.rows[gidx]                                         # [F, L]
-    vals = a.vals[gidx].astype(sr.dtype)
-    xv = jnp.where(ok_col[None, :], xs[:, safe_j].astype(sr.dtype),
-                   sr.zero)                                     # [B, F]
-    prod = sr.mul(vals[None], xv[:, :, None])                   # [B, F, L]
-    valid = in_col[None] & (xv[:, :, None] != sr.zero)
-    prod = jnp.where(valid, prod, sr.zero)
-    seg = jnp.where(in_col, rows, m)                            # [F, L] shared
-    flat = prod.reshape(b, -1).T                                # [F*L, B]
-    y = sr.segment_reduce(flat, seg.reshape(-1), m)             # [m, B]
-    return y.T
+    the expensive gather/scatter structure is batch-invariant.
+
+    Runs under the ``spmspv_union`` name scope: the union compaction
+    under ``frontier``, the column and input gathers under ``gather``."""
+    with jax.named_scope("spmspv_union"):
+        m, n = a.shape
+        b = xs.shape[0]
+        f_max = f_max or n
+        with jax.named_scope("frontier"):
+            nz_any = jnp.any(xs != sr.zero, axis=0)                 # [n]
+            count = jnp.sum(nz_any.astype(jnp.int32))
+            order = jnp.argsort(~nz_any, stable=True)
+            idx = jnp.where(jnp.arange(n) < count, order, n)[:f_max].astype(jnp.int32)
+        with jax.named_scope("gather"):
+            ok_col = idx < n
+            safe_j = jnp.where(ok_col, idx, 0)
+            start = a.col_ptr[safe_j]                               # [F]
+            length = a.col_ptr[safe_j + 1] - start
+            offs = jnp.arange(a.max_col_nnz, dtype=jnp.int32)       # [L]
+            gidx = start[:, None] + offs[None, :]                   # [F, L]
+            in_col = offs[None, :] < length[:, None]
+            gidx = jnp.where(in_col, gidx, a.nnz_max - 1)
+            rows = a.rows[gidx]                                     # [F, L]
+            vals = a.vals[gidx].astype(sr.dtype)
+            xv = jnp.where(ok_col[None, :], xs[:, safe_j].astype(sr.dtype),
+                           sr.zero)                                 # [B, F]
+        prod = sr.mul(vals[None], xv[:, :, None])                   # [B, F, L]
+        valid = in_col[None] & (xv[:, :, None] != sr.zero)
+        prod = jnp.where(valid, prod, sr.zero)
+        seg = jnp.where(in_col, rows, m)                            # [F, L] shared
+        flat = prod.reshape(b, -1).T                                # [F*L, B]
+        y = sr.segment_reduce(flat, seg.reshape(-1), m)             # [m, B]
+        return y.T
 
 
 def spmspv(a, x: Frontier, sr: Semiring, impl: str = "auto") -> Array:

@@ -69,16 +69,20 @@ def spmv_batch(a, xs: Array, sr: Semiring, impl: str = "auto") -> Array:
     (multi-query traversal, §4 many-source regime). Element formats share
     one segment-id vector across the block, so the whole batch reduces in a
     single B-lane ⊕-segment-reduce (data transposed to [nnz, B]) — a vmapped
-    per-row scatter would serialize. Other formats fall back to vmap."""
-    if isinstance(a, (COOMatrix, CSRMatrix)):
-        m, n = a.shape
-        seg = a.seg_ids if isinstance(a, CSRMatrix) else a.rows
-        ok = seg < m
-        xj = xs[:, jnp.where(ok, a.cols, 0)]                   # [B, nnz]
-        prod = sr.mul(a.vals.astype(sr.dtype)[None], xj.astype(sr.dtype))
-        prod = jnp.where(ok[None], prod, sr.zero)
-        return sr.segment_reduce(prod.T, jnp.where(ok, seg, m), m).T
-    return jax.vmap(lambda x: spmv(a, x, sr, impl=impl))(xs)
+    per-row scatter would serialize. Other formats fall back to vmap.
+    Runs under the ``spmv_batch`` name scope, its input gather under
+    ``gather``."""
+    with jax.named_scope("spmv_batch"):
+        if isinstance(a, (COOMatrix, CSRMatrix)):
+            m, n = a.shape
+            seg = a.seg_ids if isinstance(a, CSRMatrix) else a.rows
+            ok = seg < m
+            with jax.named_scope("gather"):
+                xj = xs[:, jnp.where(ok, a.cols, 0)]           # [B, nnz]
+            prod = sr.mul(a.vals.astype(sr.dtype)[None], xj.astype(sr.dtype))
+            prod = jnp.where(ok[None], prod, sr.zero)
+            return sr.segment_reduce(prod.T, jnp.where(ok, seg, m), m).T
+        return jax.vmap(lambda x: spmv(a, x, sr, impl=impl))(xs)
 
 
 def spmv(a, x: Array, sr: Semiring, impl: str = "auto") -> Array:

@@ -14,6 +14,11 @@ kernel-choice trace and per-query iteration counts).
 independent, so the block row-shards with no cross-device traffic beyond
 the scalar convergence reduction.
 
+Each runner's loop body runs under a name scope (``bfs_step``,
+``sssp_step``, ``ppr_step``), as do the matvecs beneath it (core.spmv,
+core.spmspv, ``Semiring.segment_reduce``), so a device trace names the
+ops of one iteration by what they do.
+
 Every runner takes the engine's matrices as jit arguments
 (:class:`BatchRunner`): the compiled program's size does not grow with the
 graph, and one program serves any graph of the same shapes.
@@ -130,6 +135,7 @@ def make_bfs_multi(engine: GraphEngine, batch: int, max_iters: int = 64,
             _f, _v, _l, it, done, _its, _d, _k = state
             return (~jnp.all(done)) & (it < max_iters)
 
+        @jax.named_scope("bfs_step")
         def body(state):
             frontier, visited, levels, it, done, iters, dens, kern = state
             active = ~done
@@ -176,6 +182,7 @@ def _relax_block(engine: GraphEngine, policy: str, max_iters: int,
         _di, _ch, it, done, _its, _d, _k = state
         return (~jnp.all(done)) & (it < max_iters)
 
+    @jax.named_scope("sssp_step")
     def body(state):
         dist, changed, it, done, iters, dens, kern = state
         active = ~done
@@ -272,6 +279,7 @@ def make_ppr_multi(engine: GraphEngine, batch: int, alpha: float = 0.85,
             _r, it, res, _its, _d, _k = state
             return jnp.any(res > tol) & (it < max_iters)
 
+        @jax.named_scope("ppr_step")
         def body(state):
             r, it, res, iters, dens, kern = state
             active = res > tol

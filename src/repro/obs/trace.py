@@ -4,28 +4,36 @@ A :class:`Tracer` collects :class:`Span` records — name, wall-clock
 interval, and free-form attributes (phase, strategy, device count, bytes
 on wire, …) — and exports them as Chrome-trace JSON (the ``traceEvents``
 array format), loadable in ``chrome://tracing`` and https://ui.perfetto.dev.
+A live span also writes itself into the JAX profiler's own trace whenever
+a profiler session is active, so the same spans read beside the device's
+ops on the profiler's clock.
 
 Design constraints, in order:
 
-1. **Disabled is free.**  No tracer installed (the default) means every
-   instrumentation site is one module-global ``None`` check; the
-   module-level :func:`span` helper returns the shared :data:`NULL_SPAN`
-   identity context manager — the same object every call, zero
-   allocations (asserted in tests/test_obs.py).  Hot paths that would
-   otherwise build a kwargs dict should fetch :func:`active` once and
-   branch on ``None`` (see core.pipeline for the idiom).
-2. **Enabled is blocking-accurate.**  JAX dispatch is asynchronous, so a
-   span around a bare dispatch measures nothing.  Instrumented phase
-   closures therefore ``block_until_ready`` *inside* their span when a
-   tracer is installed — tracing observes the paper's per-phase blocking
-   schedule (benchmarks/phases.py's accounting), which is exactly what
-   makes per-phase span sums comparable to wall time and to the cost
-   model.  Values are never changed by the extra syncs: traced and
-   untraced runs are bit-identical (benchmarks/phase_trace.py asserts
-   it).
+1. **Disabled is free.**  With no tracer installed and no profiler
+   session (the default) every instrumentation site costs one
+   module-global ``None`` check plus ``TraceAnnotation.is_enabled()``;
+   the module-level :func:`span` helper returns the shared
+   :data:`NULL_SPAN` identity context manager — the same object every
+   call, zero allocations (asserted in tests/test_obs.py).  Hot paths
+   that would otherwise build a kwargs dict per element should fetch
+   :func:`active` once and branch on ``None`` (the phase closures do).
+2. **Live spans share the device's clock.**  A live span enters a
+   ``jax.profiler.TraceAnnotation(name, **attrs)`` whenever a profiler
+   session is active, and records into the installed :class:`Tracer`
+   (if any) as before.  Its attributes become the annotation's stats
+   (as given at entry; ``set()`` reaches only the Tracer).  Served-path
+   spans add no host sync: the device's time is read from the device
+   trace, which shares their clock, not from span lengths.  The
+   per-phase closures of :mod:`repro.core.distributed` are the one
+   exception: with a Tracer installed they ``block_until_ready`` inside
+   their span, so per-phase span sums compare with wall time and the
+   cost model (benchmarks/phases.py's accounting); values never change
+   (benchmarks/phase_trace.py asserts traced ≡ untraced).
 3. **Spans are data.**  A span is (name, t0, t1, attrs); retrospective
    intervals (e.g. a request's enqueue wait, known only at flush time)
-   are first-class via :meth:`Tracer.add_span`.
+   are first-class via :meth:`Tracer.add_span` — in the Tracer's memory
+   only, since nothing enters the profiler's trace after the fact.
 4. **Stitching is ambient.**  :meth:`Tracer.context` opens a
    thread-local block of ambient attributes: every span recorded on
    that thread while the block is open — from any instrumentation site,
@@ -49,6 +57,11 @@ import json
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+# Whether a JAX profiler session is active (a C++ call, about 0.1 us).
+_profiling = TraceAnnotation.is_enabled
 
 
 @dataclasses.dataclass
@@ -87,26 +100,38 @@ NULL_SPAN = _NullSpan()
 
 
 class _LiveSpan:
-    """An in-flight span: context-manager entry stamps t0, exit stamps t1
-    and hands the record to the tracer. ``set(**attrs)`` adds attributes
-    mid-flight (e.g. bytes known only after the phase ran)."""
+    """An in-flight span: context-manager entry stamps t0 (and enters a
+    profiler ``TraceAnnotation`` when a session is active), exit stamps
+    t1 and hands the record to the tracer, if there is one.
+    ``set(**attrs)`` adds attributes mid-flight (e.g. bytes known only
+    after the phase ran); they reach the Tracer, not the annotation."""
 
-    __slots__ = ("_tracer", "name", "attrs", "t0", "t1")
+    __slots__ = ("_tracer", "name", "attrs", "t0", "t1", "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
+    def __init__(self, tracer: Optional["Tracer"], name: str,
+                 attrs: Dict[str, Any]):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
         self.t0 = 0.0
         self.t1 = 0.0
+        self._annotation: Optional[TraceAnnotation] = None
 
     def __enter__(self) -> "_LiveSpan":
+        if _profiling():
+            self._annotation = TraceAnnotation(self.name, **self.attrs)
+            self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.t1 = time.perf_counter()
-        self._tracer._record(Span(self.name, self.t0, self.t1, self.attrs))
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
+        if self._tracer is not None:
+            self._tracer._record(Span(self.name, self.t0, self.t1,
+                                      self.attrs))
         return False
 
     def set(self, **attrs) -> "_LiveSpan":
@@ -153,7 +178,10 @@ class Tracer:
 
     def add_span(self, name: str, t0: float, t1: float, **attrs) -> Span:
         """Record a retrospective interval from explicit perf_counter
-        stamps (e.g. enqueue wait: submit time → flush time)."""
+        stamps (e.g. enqueue wait: submit time → flush time). It stays
+        in this Tracer's memory: nothing can enter the profiler's trace
+        after the fact, so what a profiled run needs of such intervals
+        is kept as counters instead (e.g. ``SLOAccount.queue_wait_s``)."""
         s = Span(name, t0, t1, attrs)
         self._record(s)
         return s
@@ -290,13 +318,17 @@ class tracing:
 
 
 def span(name: str, **attrs):
-    """Module-level convenience: a span on the active tracer, or the
-    shared :data:`NULL_SPAN` identity context manager when disabled.
+    """Module-level convenience: a span on the active tracer; with no
+    tracer but a profiler session active, a span that writes only the
+    profiler's ``TraceAnnotation``; with neither, the shared
+    :data:`NULL_SPAN` identity context manager.
 
     Note the kwargs dict is built before the enabled check — per-element
     hot loops should use ``t = active()`` + an explicit ``None`` branch
-    instead (the phase closures and pipelines do)."""
+    instead (the phase closures do)."""
     t = _active
-    if t is None:
-        return NULL_SPAN
-    return t.span(name, **attrs)
+    if t is not None:
+        return t.span(name, **attrs)
+    if _profiling():
+        return _LiveSpan(None, name, attrs)
+    return NULL_SPAN

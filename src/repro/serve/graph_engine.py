@@ -256,8 +256,9 @@ class GraphQueryServer:
                          "plan_replans": 0}
         # Per-server latency instruments (repro.obs.metrics): enqueue
         # wait / flush latency / bucket+payload times as streaming
-        # histograms, queue depth and LRU hit rate as gauges. Surfaced
-        # (as plain copies) under stats()["latency"].
+        # histograms, queue depth as a gauge. Surfaced (as plain copies,
+        # with the LRU hit rate read from the cache) under
+        # stats()["latency"].
         self.metrics = MetricsRegistry()
 
     def _engine_key_for(self, graph: Graph) -> str:
@@ -513,21 +514,15 @@ class GraphQueryServer:
             self.counters["batches"] += 1
             self.metrics.histogram("batch_size", least=1.0).observe(
                 float(len(bucket)))
-            tr = trace.active()
             t0 = time.perf_counter()
-            if tr is None:
+            # the bucket's wait-for-compute (the first host pull blocks
+            # on the device result) apart from the payload conversion
+            with trace.span("serve/bucket_compute", algorithm=algorithm,
+                            rows=len(bucket)):
                 rows, iters = self._to_host(algorithm, res)
+            with trace.span("serve/payload", algorithm=algorithm,
+                            rows=len(bucket)):
                 out = self._payloads(rows, iters, bucket)
-            else:
-                # split the bucket's wait-for-compute (the first host
-                # pull blocks on the device result) from the pure
-                # payload-dict conversion
-                with tr.span("serve/bucket_compute", algorithm=algorithm,
-                             size=len(bucket)):
-                    rows, iters = self._to_host(algorithm, res)
-                with tr.span("serve/payload", algorithm=algorithm,
-                             size=len(bucket)):
-                    out = self._payloads(rows, iters, bucket)
             self.metrics.histogram("bucket_s").observe(
                 time.perf_counter() - t0)
             return out
@@ -620,10 +615,11 @@ class GraphQueryServer:
         submission order, results attached.
 
         Observability per flush: queue depth and per-query enqueue wait
-        are recorded into the metrics registry (stats()["latency"]); with
+        are recorded into the metrics registry (stats()["latency"]); the
+        flush's work runs in a live ``serve/flush`` span (``n_requests``;
+        see obs.trace: the profiler's trace and/or the Tracer), and with
         a tracer installed each query additionally gets a retrospective
-        ``serve/enqueue_wait`` span (submit stamp → flush start) and the
-        flush itself a ``serve/flush`` span.
+        ``serve/enqueue_wait`` span (submit stamp → flush start).
 
         Edge semantics (pinned in tests/test_async_server.py): flushing
         an **empty** queue is a free no-op — ``[]``, no engine work, no
@@ -638,80 +634,75 @@ class GraphQueryServer:
         pending = [req for req in queue if req.result is None]
         if not pending:
             return queue       # every ticket already resolved: no-op
-        t0 = time.perf_counter()
-        tr = trace.active()
-        reg = self.metrics
-        reg.gauge("queue_depth").set(float(len(queue)))
-        wait_h = reg.histogram("enqueue_wait_s")
-        for req in pending:
-            if req.submitted_at:
-                wait_h.observe(t0 - req.submitted_at)
-                if tr is not None:
-                    tr.add_span("serve/enqueue_wait", req.submitted_at, t0,
-                                algorithm=req.algorithm, source=req.source)
-        by_alg: Dict[str, List[GraphRequest]] = {}
-        for req in pending:
-            by_alg.setdefault(req.algorithm, []).append(req)
+        with trace.span("serve/flush", n_requests=len(pending)):
+            t0 = time.perf_counter()
+            tr = trace.active()
+            reg = self.metrics
+            reg.gauge("queue_depth").set(float(len(queue)))
+            wait_h = reg.histogram("enqueue_wait_s")
+            for req in pending:
+                if req.submitted_at:
+                    wait_h.observe(t0 - req.submitted_at)
+                    if tr is not None:
+                        tr.add_span("serve/enqueue_wait", req.submitted_at, t0,
+                                    algorithm=req.algorithm, source=req.source)
+            by_alg: Dict[str, List[GraphRequest]] = {}
+            for req in pending:
+                by_alg.setdefault(req.algorithm, []).append(req)
 
-        for algorithm, reqs in by_alg.items():
-            if algorithm in GLOBAL_ALGORITHMS:
-                # Probe the LRU once per request, exactly like the
-                # traversal path, so stats["cache_hits"] and
-                # LRUCache.hits stay reconcilable across query kinds.
-                # The first miss computes once into a flush-local payload;
-                # fan-out askers resolve from the LRU when it accepted the
-                # put, and from the local payload (counted as dedup, like
-                # the traversal path) when caching is disabled/evicting —
-                # the compute-once contract never depends on the cache.
-                key = (self.engine_key, algorithm, GLOBAL)
-                fresh = None
+            for algorithm, reqs in by_alg.items():
+                if algorithm in GLOBAL_ALGORITHMS:
+                    # Probe the LRU once per request, exactly like the
+                    # traversal path, so stats["cache_hits"] and
+                    # LRUCache.hits stay reconcilable across query kinds.
+                    # The first miss computes once into a flush-local payload;
+                    # fan-out askers resolve from the LRU when it accepted the
+                    # put, and from the local payload (counted as dedup, like
+                    # the traversal path) when caching is disabled/evicting —
+                    # the compute-once contract never depends on the cache.
+                    key = (self.engine_key, algorithm, GLOBAL)
+                    fresh = None
+                    for req in reqs:
+                        hit = self.cache.get(key)
+                        if hit is not None:
+                            # shallow copy: numpy payloads stay shared (read-only)
+                            req.result = dict(hit)
+                            req.cached = True
+                            self.counters["cache_hits"] += 1
+                        elif fresh is not None:
+                            req.result = dict(fresh)
+                            self.counters["deduped"] += 1
+                        else:
+                            fresh = self._run_global(algorithm)
+                            self.cache.put(key, fresh)
+                            req.result = dict(fresh)
+                    continue
+
+                misses: List[int] = []
+                seen = set()
                 for req in reqs:
-                    hit = self.cache.get(key)
+                    hit = self.cache.get((self.engine_key, algorithm, req.source))
                     if hit is not None:
-                        # shallow copy: numpy payloads stay shared (read-only)
+                        # shallow copy: the dict is per-request, the numpy
+                        # payloads stay shared (treat them as read-only)
                         req.result = dict(hit)
                         req.cached = True
                         self.counters["cache_hits"] += 1
-                    elif fresh is not None:
-                        req.result = dict(fresh)
-                        self.counters["deduped"] += 1
+                    elif req.source not in seen:
+                        seen.add(req.source)
+                        misses.append(req.source)
                     else:
-                        fresh = self._run_global(algorithm)
-                        self.cache.put(key, fresh)
-                        req.result = dict(fresh)
-                continue
+                        self.counters["deduped"] += 1
+                fresh: Dict[int, Dict[str, Any]] = (
+                    self._run_batches(algorithm, misses) if misses else {})
+                for src, payload in fresh.items():
+                    self.cache.put((self.engine_key, algorithm, src), payload)
+                for req in reqs:
+                    if req.result is None:
+                        req.result = dict(fresh[req.source])
 
-            misses: List[int] = []
-            seen = set()
-            for req in reqs:
-                hit = self.cache.get((self.engine_key, algorithm, req.source))
-                if hit is not None:
-                    # shallow copy: the dict is per-request, the numpy
-                    # payloads stay shared (treat them as read-only)
-                    req.result = dict(hit)
-                    req.cached = True
-                    self.counters["cache_hits"] += 1
-                elif req.source not in seen:
-                    seen.add(req.source)
-                    misses.append(req.source)
-                else:
-                    self.counters["deduped"] += 1
-            fresh: Dict[int, Dict[str, Any]] = (
-                self._run_batches(algorithm, misses) if misses else {})
-            for src, payload in fresh.items():
-                self.cache.put((self.engine_key, algorithm, src), payload)
-            for req in reqs:
-                if req.result is None:
-                    req.result = dict(fresh[req.source])
-
-        self.counters["served"] += len(pending)
-        t1 = time.perf_counter()
-        reg.histogram("flush_s").observe(t1 - t0)
-        cs = self.cache.stats()
-        probes = cs["hits"] + cs["misses"]
-        reg.gauge("lru_hit_rate").set(cs["hits"] / probes if probes else 0.0)
-        if tr is not None:
-            tr.add_span("serve/flush", t0, t1, n_requests=len(pending))
+            self.counters["served"] += len(pending)
+            reg.histogram("flush_s").observe(time.perf_counter() - t0)
         return queue
 
 
@@ -842,20 +833,24 @@ class AsyncGraphServer:
         the non-reentrant engine safe while other tenants' windows — and
         other tenants' mutations — proceed concurrently.
 
-        With a tracer installed, each ticket gets a retrospective
-        ``serve/window`` span (its submit stamp → dispatch) and the
-        whole drain runs inside an ambient ``window_id``/``tenant``/
-        ``request_ids`` context (obs.trace.Tracer.context) — every span
-        the flush emits below here (``serve/flush``, bucket pipeline,
-        phase closures) inherits the ids, stitching the lifecycle."""
+        The drain (lock held) runs in a live ``serve/drain`` span
+        (``tenant``, ``window_id``, ``tickets``). With a tracer
+        installed, each ticket also gets a retrospective ``serve/window``
+        span (its submit stamp → dispatch) and the whole drain runs
+        inside an ambient ``window_id``/``tenant``/``request_ids``
+        context (obs.trace.Tracer.context) — every span the flush emits
+        below here (``serve/flush``, bucket pipeline, phase closures)
+        inherits the ids, stitching the lifecycle."""
         server = self._tenants[name]
         slo = self._slo[name]
         tr = trace.active()
-        with self._tenant_locks[name]:
+        wid = tickets[0].window_id if tickets else -1
+        with self._tenant_locks[name], trace.span(
+                "serve/drain", tenant=name, window_id=wid,
+                tickets=len(tickets)):
             if tr is None or not tickets:
                 self._drain_window(server, slo, tickets)
                 return
-            wid = tickets[0].window_id
             now_pc = time.perf_counter()
             for tk in tickets:
                 if tk.submitted_pc:

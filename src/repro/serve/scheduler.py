@@ -240,6 +240,12 @@ class SLOAccount:
     (``QueryTicket.resolve`` re-resolution is a no-op, so "first
     resolve" is well-defined even under duplicate drains).
 
+    Each record also splits the ticket's latency on the scheduler clock:
+    ``queue_wait_s`` sums ``dispatched_at - admitted_at`` (waiting for
+    its window, and for the window in flight ahead of it) and
+    ``service_s`` sums ``resolved_at - dispatched_at`` (its window's
+    drain).
+
     One lock guards the counters *and* both histograms, so conservation
     holds in **every** ``snapshot()``, never just at quiescence::
 
@@ -249,7 +255,8 @@ class SLOAccount:
     """
 
     __slots__ = ("_lock", "resolved", "goodput", "deadline_misses",
-                 "no_deadline", "slack_s", "lateness_s")
+                 "no_deadline", "slack_s", "lateness_s", "queue_wait_s",
+                 "service_s")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -259,12 +266,16 @@ class SLOAccount:
         self.no_deadline = 0
         self.slack_s = Histogram("slack_s")
         self.lateness_s = Histogram("lateness_s")
+        self.queue_wait_s = 0.0
+        self.service_s = 0.0
 
     def record(self, ticket: QueryTicket) -> Optional[float]:
         """Classify one resolved ticket; returns its signed slack."""
         slack = ticket.slack()
         with self._lock:
             self.resolved += 1
+            self.queue_wait_s += ticket.dispatched_at - ticket.admitted_at
+            self.service_s += ticket.resolved_at - ticket.dispatched_at
             if slack is None:
                 self.no_deadline += 1
             else:
@@ -283,7 +294,9 @@ class SLOAccount:
                     "deadline_misses": self.deadline_misses,
                     "no_deadline": self.no_deadline,
                     "slack_s": self.slack_s.summary(),
-                    "lateness_s": self.lateness_s.summary()}
+                    "lateness_s": self.lateness_s.summary(),
+                    "queue_wait_s": self.queue_wait_s,
+                    "service_s": self.service_s}
 
 
 def _edf_key(tk: QueryTicket) -> Tuple[float, int, int]:

@@ -428,6 +428,34 @@ def test_ticket_wait_times_out_unresolved():
 # SLO accounting: deadline misses, slack, abandonment (fake clock)
 # ---------------------------------------------------------------------------
 
+def test_slo_splits_latency_into_queue_wait_and_service(graph):
+    """Admitted at 0, dispatched at 0.3, resolved at 1.0: 0.3 s of queue
+    wait and 0.7 s of service, counted once (a second resolve of the
+    same ticket adds nothing)."""
+    clock = FakeClock()
+    srv = AsyncGraphServer(clock=clock, max_wait=0.3)
+    tenant = srv.add_tenant("t", graph, batch_size=8)
+    flush = tenant.flush
+
+    def slow_flush():
+        clock.advance(0.7)
+        return flush()
+
+    tenant.flush = slow_flush
+    tk = srv.submit("t", "bfs", 0)
+    clock.advance(0.3)
+    assert srv.poll() == 1
+    assert (tk.admitted_at, tk.dispatched_at, tk.resolved_at) == \
+        pytest.approx((0.0, 0.3, 1.0))
+    slo = srv.stats("t")["slo"]
+    assert slo["queue_wait_s"] == pytest.approx(0.3)
+    assert slo["service_s"] == pytest.approx(0.7)
+    srv._drain_tenant("t", [tk])                # a duplicate drain
+    again = srv.stats("t")["slo"]
+    assert (again["queue_wait_s"], again["service_s"], again["resolved"]) \
+        == (slo["queue_wait_s"], slo["service_s"], slo["resolved"])
+
+
 def test_slo_deadline_miss_accounting(graph):
     """Misses are classified by signed slack at resolve time, counted
     exactly once, and conserved: goodput + misses + no-deadline ==

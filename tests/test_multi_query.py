@@ -2,6 +2,11 @@
 must match the corresponding single-source run — outputs, per-query
 iteration counts, and the adaptive kernel-switch trace — on both a
 scale-free and a regular synthetic graph (ISSUE 1 acceptance)."""
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -12,6 +17,7 @@ from repro.graphs import (
 )
 from repro.graphs.cost_model import trained_stump
 from repro.graphs.engine import build_engine
+from repro.graphs.multi import make_bfs_multi, make_ppr_multi, make_sssp_multi
 
 B = 8
 GRAPHS = {
@@ -206,3 +212,51 @@ def test_bfs_runner_takes_graph_as_arguments():
         np.testing.assert_array_equal(np.asarray(res.levels),
                                       np.asarray(ref.levels))
     assert abs(sizes[1] - sizes[0]) < 0.01 * sizes[0], sizes
+
+
+class _NoScope(contextlib.ContextDecorator):
+    """Stands in for ``jax.named_scope``: no name, no metadata."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+SCOPED = {
+    "bfs": (make_bfs_multi, BOOL_OR_AND, {}, "bfs_step"),
+    "sssp": (make_sssp_multi, MIN_PLUS, {"weighted": True, "seed": 5},
+             "sssp_step"),
+    "ppr": (make_ppr_multi, PLUS_TIMES, {"normalize": True}, "ppr_step"),
+}
+
+
+def _scoped_run(alg, g, stump, sources):
+    """(op_name scopes of the compiled runner, its result) on a fresh
+    engine, so no runner compiled under another scoping is reused."""
+    make, sr, kw, _step = SCOPED[alg]
+    runner = make(build_engine(g, sr, stump, **kw), len(sources))
+    src = jnp.asarray(sources, jnp.int32)
+    hlo = runner.jitted.lower(runner.mats, src).compile().as_text()
+    # every component of each op's name path but the op itself
+    scopes = {part for name in re.findall(r'op_name="([^"]*)"', hlo)
+              for part in name.split("/")[:-1]}
+    return scopes, jax.device_get(runner(src))
+
+
+@pytest.mark.parametrize("alg", sorted(SCOPED))
+def test_runner_ops_carry_name_scopes(alg, stump, monkeypatch):
+    """The compiled runner's HLO metadata names its loop step, the input
+    gathers, the union compaction and the segment reduce; the scopes are
+    metadata only, so the answers equal an unscoped build's bit for bit."""
+    g = generate("face", scale=0.15, seed=1)
+    sources = [0, 3, 5, 7]
+    scopes, got = _scoped_run(alg, g, stump, sources)
+    assert {SCOPED[alg][3], "segment_reduce", "gather", "frontier",
+            "spmv_batch", "spmspv_union"} <= scopes
+    monkeypatch.setattr(jax, "named_scope", lambda name: _NoScope())
+    bare, want = _scoped_run(alg, g, stump, sources)
+    assert not {SCOPED[alg][3], "segment_reduce", "gather"} & bare
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -146,6 +146,44 @@ def test_ambient_context_exception_safe_and_disabled_path_unchanged():
     assert trace.span("x", a=1) is trace.NULL_SPAN
 
 
+def _profiled_events(tmp_path, name):
+    """The profiler-trace events called ``name`` under ``tmp_path``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    return [e for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for e in line.events if e.name == name]
+
+
+def test_live_span_writes_the_profiler_trace(tmp_path):
+    """With a profiler session and no tracer, a span is a TraceAnnotation
+    whose attrs become stats on the host event; with a tracer installed
+    too it lands in both; with neither it is the shared no-op again."""
+    import jax
+
+    assert trace.span("serve/flush", window_id=3) is trace.NULL_SPAN
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.span("serve/flush", window_id=3) as s:
+            assert s is not trace.NULL_SPAN
+        with trace.tracing() as t:
+            with trace.span("serve/payload", algorithm="bfs", rows=8):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    assert trace.span("serve/flush", window_id=3) is trace.NULL_SPAN
+    flush, = _profiled_events(tmp_path, "serve/flush")
+    assert ("window_id", 3) in list(flush.stats)
+    assert flush.end_ns >= flush.start_ns
+    payload, = _profiled_events(tmp_path, "serve/payload")
+    assert {("algorithm", "bfs"), ("rows", 8)} <= set(payload.stats)
+    recorded, = t.filter("serve/payload")
+    assert recorded.attrs == {"algorithm": "bfs", "rows": 8}
+
+
 # ---------------------------------------------------------------------------
 # trace: recording + export
 # ---------------------------------------------------------------------------
