@@ -9,7 +9,8 @@ Padding conventions
 -------------------
 * COO/CSR/CSC pad ``rows``/``cols`` with an out-of-range index (= M or N) and
   ``vals`` with the semiring zero; XLA scatter drops out-of-range updates, so
-  padded entries are no-ops in every segment reduction.
+  padded entries are no-ops in every segment reduction. CSR's padding sits
+  past the last row's end, so its row-sorted reduction never reads it.
 * BSR pads the tile list with all-zero tiles pointing at tile-column 0, which
   are ⊕-identity contributions for every supported semiring (zero ⊗ x = zero,
   y ⊕ zero = y) — except min_plus where the pad tile value is +inf.
@@ -80,7 +81,11 @@ class COOMatrix:
 @dataclasses.dataclass
 class CSRMatrix:
     """Compressed sparse row: row_ptr [M+1], cols/vals [nnz_max] + expanded
-    row segment ids (precomputed so kernels avoid searchsorted at step time)."""
+    row segment ids (precomputed so kernels avoid searchsorted at step time).
+
+    ``max_row_nnz`` (static) bounds any single row's length; it sets the
+    step count of the row-sorted ⊕-reduction (``scan_steps``).
+    """
 
     row_ptr: Array
     cols: Array
@@ -88,17 +93,39 @@ class CSRMatrix:
     seg_ids: Array  # [nnz_max] row index per entry, padded with M
     nnz: Array
     shape: Tuple[int, int]
+    max_row_nnz: int
 
     def tree_flatten(self):
-        return (self.row_ptr, self.cols, self.vals, self.seg_ids, self.nnz), (self.shape,)
+        return ((self.row_ptr, self.cols, self.vals, self.seg_ids, self.nnz),
+                (self.shape, self.max_row_nnz))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, aux[0])
+        return cls(*children, *aux)
 
     @property
     def nnz_max(self) -> int:
         return self.cols.shape[0]
+
+    @property
+    def scan_steps(self) -> int:
+        """Doubling steps of ``Semiring.segment_reduce_sorted``: ceil(log2)
+        of the longest row, at least 8, so every matrix whose rows hold at
+        most 256 entries compiles to the same program."""
+        return max(8, (self.max_row_nnz - 1).bit_length())
+
+    def reduce_rows(self, data: Array, sr: Semiring) -> Array:
+        """⊕-reduce per-entry ``data`` [B, nnz_max] into rows: [B, M].
+
+        Where ⊕ gives the same bits in any association
+        (``sr.exact_in_any_order``), the row-sorted entries take the
+        segmented scan. A float sum keeps the scatter, which adds each row
+        in entry order as the CSC and COO reductions do, so every path of
+        a ⟨+,×⟩ traversal rounds alike."""
+        if sr.exact_in_any_order:
+            return sr.segment_reduce_sorted(data, self.seg_ids, self.row_ptr,
+                                            self.scan_steps)
+        return sr.segment_reduce(data.T, self.seg_ids, self.shape[0]).T
 
 
 @jax.tree_util.register_pytree_node_class
@@ -300,6 +327,7 @@ def build_csr(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray,
         seg_ids=coo.rows,
         nnz=coo.nnz,
         shape=shape,
+        max_row_nnz=int(counts.max(initial=1)),
     )
 
 

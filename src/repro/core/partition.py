@@ -560,10 +560,14 @@ def partition(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray,
         else:
             raise ValueError(fmt)
 
+    # Uniform static max_col_nnz / max_row_nnz across tiles (shard_map
+    # needs identical shapes and one program).
     if fmt == "csc":
-        # Uniform static max_col_nnz across tiles (shard_map needs identical shapes).
         mc = max(b.max_col_nnz for b in built)
         built = [dataclasses.replace(b, max_col_nnz=mc) for b in built]
+    if fmt == "csr":
+        mr = max(b.max_row_nnz for b in built)
+        built = [dataclasses.replace(b, max_row_nnz=mr) for b in built]
     if fmt == "bsr":
         slots = max(b.slots for b in built)
         rebuilt = []

@@ -16,6 +16,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 Array = jax.Array
 
@@ -45,6 +46,13 @@ class Semiring:
         sufficient: ⟨+,∧⟩ (triangle counting) ⊕-reduces with psum but its
         ⊗ is min, which dot would silently get wrong."""
         return self.add is jnp.add and self.mul is jnp.multiply
+
+    @property
+    def exact_in_any_order(self) -> bool:
+        """True iff ⊕-reducing in another association gives the same bits:
+        min, max and OR always, a sum only over integers."""
+        return (self.collective != "psum"
+                or jnp.issubdtype(self.dtype, jnp.integer))
 
     def add_reduce(self, x: Array, axis: int | tuple[int, ...],
                    keepdims: bool = False) -> Array:
@@ -77,6 +85,46 @@ class Semiring:
                 out = jax.ops.segment_max(data, segment_ids, num_segments)
                 return jnp.maximum(out, jnp.asarray(self.zero, out.dtype))
             raise ValueError(self.collective)
+
+    def segment_reduce_sorted(self, data: Array, segment_ids: Array,
+                              row_ptr: Array, steps: int) -> Array:
+        """⊕-reduce the row-sorted entries of a CSR matrix: ``data`` is
+        ``[B, nnz]`` (entries along the minor axis), ``segment_ids``
+        ``[nnz]`` non-decreasing, ``row_ptr`` ``[m+1]``; returns ``[B, m]``.
+
+        A segmented Hillis–Steele scan: step k ⊕-folds each entry's
+        neighbour 2^k places to its left when both lie in one row, so after
+        ``steps`` steps an entry holds the ⊕ of its row's last 2^steps
+        entries up to itself; ``steps`` ≥ log2 of the longest row makes
+        each row's last entry its total, read through ``row_ptr`` (empty
+        rows get ``zero``). No scatter and no sort: exact for min, max, OR
+        and integer sums; a float sum is only associated differently
+        (``exact_in_any_order``). Runs under the
+        ``segment_reduce`` scope, the steps under ``row_scan`` and the read
+        under ``row_ends``."""
+        with jax.named_scope("segment_reduce"):
+            zero = jnp.asarray(self.zero, data.dtype)
+            # Entries along the lanes. A column gather ``xs[:, cols]``
+            # comes out of the TPU compiler as rows of B values (B minor,
+            # padded to 128 lanes); left alone, that layout would run
+            # through every step at 16x the bytes for B = 8. The
+            # constraint makes the compiler transpose once, here.
+            v = with_layout_constraint(data, Layout(major_to_minor=(0, 1)))
+            with jax.named_scope("row_scan"):
+                for k in range(steps):
+                    s = 1 << k
+                    if s >= v.shape[-1]:
+                        break
+                    # shift right by s along the entries: one pad with a
+                    # negative high edge, which XLA fuses into the step
+                    left = jax.lax.pad(v, zero, [(0, 0, 0), (s, -s, 0)])
+                    same = jax.lax.pad(segment_ids, jnp.int32(-1),
+                                       [(s, -s, 0)]) == segment_ids
+                    v = jnp.where(same[None], self.add(v, left), v)
+            with jax.named_scope("row_ends"):
+                end = row_ptr[1:]
+                y = jnp.take(v, jnp.maximum(end - 1, 0), axis=1, mode="clip")
+                return jnp.where((end > row_ptr[:-1])[None], y, zero)
 
     def preduce(self, x: Array, axis_name: str) -> Array:
         """Distributed ⊕-reduction over a mesh axis (the paper's Merge phase,

@@ -86,7 +86,7 @@ def spmspv_csr_masked(a: CSRMatrix, x: Frontier, sr: Semiring) -> Array:
     xj = x_dense[jnp.where(ok, a.cols, 0)]
     prod = sr.mul(a.vals.astype(sr.dtype), xj)
     prod = jnp.where(ok & (xj != sr.zero), prod, sr.zero)
-    return sr.segment_reduce(prod, a.seg_ids, m)
+    return a.reduce_rows(prod[None], sr)[0]
 
 
 def spmspv_csc_gather(a: CSCMatrix, x: Frontier, sr: Semiring) -> Array:

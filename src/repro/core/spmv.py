@@ -29,13 +29,14 @@ def spmv_coo(a: COOMatrix, x: Array, sr: Semiring) -> Array:
 
 def spmv_csr(a: CSRMatrix, x: Array, sr: Semiring) -> Array:
     """CSR uses the precomputed expanded segment ids; identical math to COO
-    but entries are row-sorted so the segment reduce is a contiguous scan."""
+    but entries are row-sorted, so the ⊕-reduce can be a segmented scan
+    read at each row's end (``CSRMatrix.reduce_rows``), not a scatter."""
     m, n = a.shape
     ok = a.seg_ids < m
     xj = x[jnp.where(ok, a.cols, 0)]
     prod = sr.mul(a.vals.astype(sr.dtype), xj.astype(sr.dtype))
     prod = jnp.where(ok, prod, sr.zero)
-    return sr.segment_reduce(prod, a.seg_ids, m)
+    return a.reduce_rows(prod[None], sr)[0]
 
 
 def spmv_bsr_ref(a: BSRMatrix, x: Array, sr: Semiring) -> Array:
@@ -67,20 +68,26 @@ def spmv_bsr_ref(a: BSRMatrix, x: Array, sr: Semiring) -> Array:
 def spmv_batch(a, xs: Array, sr: Semiring, impl: str = "auto") -> Array:
     """Batched SpMV: Y = A ⊕.⊗ Xᵀ with a [B, n] block of dense input vectors
     (multi-query traversal, §4 many-source regime). Element formats share
-    one segment-id vector across the block, so the whole batch reduces in a
-    single B-lane ⊕-segment-reduce (data transposed to [nnz, B]) — a vmapped
-    per-row scatter would serialize. Other formats fall back to vmap.
+    one segment-id vector across the block, so the whole batch reduces in
+    one pass: CSR by ``CSRMatrix.reduce_rows`` (the row-sorted scan on the
+    [B, nnz] products, or a float sum's scatter), COO, whose order is not
+    promised, with a B-lane ⊕-scatter (data transposed to [nnz, B]) — a
+    vmapped per-row scatter would serialize. Other formats fall back to
+    vmap.
     Runs under the ``spmv_batch`` name scope, its input gather under
     ``gather``."""
     with jax.named_scope("spmv_batch"):
         if isinstance(a, (COOMatrix, CSRMatrix)):
             m, n = a.shape
-            seg = a.seg_ids if isinstance(a, CSRMatrix) else a.rows
+            csr = isinstance(a, CSRMatrix)
+            seg = a.seg_ids if csr else a.rows
             ok = seg < m
             with jax.named_scope("gather"):
                 xj = xs[:, jnp.where(ok, a.cols, 0)]           # [B, nnz]
             prod = sr.mul(a.vals.astype(sr.dtype)[None], xj.astype(sr.dtype))
             prod = jnp.where(ok[None], prod, sr.zero)
+            if csr:
+                return a.reduce_rows(prod, sr)
             return sr.segment_reduce(prod.T, jnp.where(ok, seg, m), m).T
         return jax.vmap(lambda x: spmv(a, x, sr, impl=impl))(xs)
 

@@ -16,8 +16,8 @@ the scalar convergence reduction.
 
 Each runner's loop body runs under a name scope (``bfs_step``,
 ``sssp_step``, ``ppr_step``), as do the matvecs beneath it (core.spmv,
-core.spmspv, ``Semiring.segment_reduce``), so a device trace names the
-ops of one iteration by what they do.
+core.spmspv, their ⊕-reductions under ``segment_reduce``), so a device
+trace names the ops of one iteration by what they do.
 
 Every runner takes the engine's matrices as jit arguments
 (:class:`BatchRunner`): the compiled program's size does not grow with the

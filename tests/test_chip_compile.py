@@ -19,9 +19,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.formats import CSRMatrix
 from repro.core.semiring import (
     BOOL_OR_AND, MIN_PLUS, PLUS_AND, PLUS_TIMES,
 )
+from repro.core.spmv import spmv_batch
 from repro.kernels.semiring_spmv import (
     semiring_spmv_fused_padded, semiring_spmv_padded, semiring_spmv_sell,
 )
@@ -132,3 +134,24 @@ def test_bfs_runner_compiles_for_v5e(one_chip):
     entry_bytes = sum(a.size * a.dtype.itemsize
                       for a in (csr.cols, csr.vals, csr.seg_ids))
     assert mem.argument_size_in_bytes >= entry_bytes
+
+
+@pytest.mark.parametrize("sr", [BOOL_OR_AND, MIN_PLUS], ids=lambda s: s.name)
+def test_csr_spmv_batch_has_no_scatter_for_v5e(one_chip, sr):
+    """The batched CSR SpMV, graph as arguments, reduces its row-sorted
+    products by the segmented scan: the compiled program holds no scatter
+    and no sort, and the scan runs with the entries in the lanes."""
+    n, nnz = 1 << 14, 1 << 19
+    a = CSRMatrix(_arg((n + 1,), jnp.int32, one_chip),
+                  _arg((nnz,), jnp.int32, one_chip),
+                  _arg((nnz,), sr.dtype, one_chip),
+                  _arg((nnz,), jnp.int32, one_chip),
+                  _arg((), jnp.int32, one_chip), (n, n), max_row_nnz=5000)
+    xs = _arg((8, n), sr.dtype, one_chip)
+    text = jax.jit(lambda a, xs: spmv_batch(a, xs, sr)).lower(
+        a, xs).compile().as_text()
+    assert "scatter(" not in text and "sort(" not in text
+    steps = [ln for ln in text.splitlines()
+             if "row_scan" in ln and " fusion(" in ln and f"[8,{nnz}]" in ln]
+    assert len(steps) >= a.scan_steps - 1, len(steps)
+    assert all(f"[8,{nnz}]{{1,0" in ln for ln in steps), steps[0]

@@ -41,6 +41,7 @@ def test_spmv_formats_match_oracle(sr, n, density):
     xj = jnp.asarray(x, sr.dtype)
     coo = build_coo(rows, cols, vals, (n, n), sr)
     csr = build_csr(rows, cols, vals, (n, n), sr)
+    assert csr.max_row_nnz == max(1, np.bincount(rows, minlength=n).max())
     np.testing.assert_allclose(np.asarray(spmv(coo, xj, sr)), oracle, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(spmv(csr, xj, sr)), oracle, rtol=1e-5)
     bsr = build_bsr(rows, cols, vals, (n, n), sr, block=(16, 16))

@@ -76,6 +76,9 @@ def test_partition_unpartition_identity(family, balance, grid, fmt):
     np.testing.assert_array_equal(c2, cols[order])
     np.testing.assert_array_equal(v2, vals[order])
     assert sum(pm.plan.tile_nnz) == rows.shape[0]
+    if fmt == "csr":  # one static row bound for the stacked tiles
+        longest = np.diff(np.asarray(pm.parts.row_ptr), axis=1).max()
+        assert pm.parts.max_row_nnz == max(1, longest)
 
 
 def test_partition_unpartition_identity_bsr():
