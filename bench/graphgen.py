@@ -1,18 +1,14 @@
-"""Graph generators of the benchmark's deployments, in vectorised numpy.
+"""The graphs of the benchmark's deployments, in vectorised numpy.
 
 These are the benchmark's own copies: they import nothing of the program,
 so a change to the program cannot change the graphs it is measured on.
 
-* ``kronecker`` follows the Graph500 reference generator
-  (``kronecker_generator.m``, specification section 3): ``edgefactor * 2**S``
-  edge tuples, each of its ``S`` bit levels drawn with the initiator
-  probabilities A, B, C (D = 1 - A - B - C).
-* ``urand`` follows the GAP Benchmark Suite's ``-u`` generator: the same
-  number of tuples with both endpoints uniform over the vertices.
-
-Both then build the graph as Graph500's kernel 1 and GAP's builder do:
-undirected (each edge stored in both directions), self-loops and duplicate
-edges dropped.
+A configuration's ``generator`` names its graph family, the module
+``bench/generators/<generator>.py`` whose ``tuples(cfg, rng)`` draws the
+edge tuples (for example ``kronecker``, Graph500's generator, and
+``urand``, GAP's ``-u``). Every family's tuples then become the graph as
+Graph500's kernel 1 and GAP's builder build it: undirected (each edge
+stored in both directions), self-loops and duplicate edges dropped.
 
 Relabelling: Graph500 relabels vertices by a random permutation. Here the
 edge tuples are drawn from the configuration's fixed ``structure_seed`` and
@@ -26,27 +22,7 @@ from __future__ import annotations
 
 import numpy as np
 
-
-def _kronecker_tuples(scale: int, edgefactor: int, a: float, b: float,
-                      c: float, rng: np.random.Generator):
-    """Graph500 ``kronecker_generator`` bit loop, without its permutations."""
-    m = edgefactor << scale
-    ab = a + b
-    c_norm = c / (1.0 - ab)
-    a_norm = a / ab
-    src = np.zeros(m, np.int64)
-    dst = np.zeros(m, np.int64)
-    for level in range(scale):
-        ii = rng.random(m) > ab
-        jj = rng.random(m) > np.where(ii, c_norm, a_norm)
-        src |= ii.astype(np.int64) << level
-        dst |= jj.astype(np.int64) << level
-    return src, dst
-
-
-def _urand_tuples(scale: int, edgefactor: int, rng: np.random.Generator):
-    n, m = 1 << scale, edgefactor << scale
-    return rng.integers(0, n, m), rng.integers(0, n, m)
+from bench import spec
 
 
 def _undirected_simple(src: np.ndarray, dst: np.ndarray, n: int):
@@ -61,16 +37,8 @@ def _undirected_simple(src: np.ndarray, dst: np.ndarray, n: int):
 
 def structure(cfg: dict):
     """The configuration's edge set before relabelling: (rows, cols, n)."""
-    scale, ef = int(cfg["scale"]), int(cfg["edgefactor"])
     rng = np.random.default_rng(int(cfg["structure_seed"]))
-    if cfg["generator"] == "kronecker":
-        a, b, c = (float(cfg["initiator"][k]) for k in ("A", "B", "C"))
-        src, dst = _kronecker_tuples(scale, ef, a, b, c, rng)
-    elif cfg["generator"] == "urand":
-        src, dst = _urand_tuples(scale, ef, rng)
-    else:
-        raise ValueError(f"unknown generator {cfg['generator']!r}")
-    n = 1 << scale
+    src, dst, n = spec.generator(cfg["generator"]).tuples(cfg, rng)
     rows, cols = _undirected_simple(src, dst, n)
     return rows, cols, n
 

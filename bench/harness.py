@@ -19,7 +19,7 @@ import time
 
 import numpy as np
 
-from bench import devtrace, graphgen, reference, roofline, spec, traffic
+from bench import devtrace, graphgen, spec, traffic
 
 # Queries per answer sample drawn from the seed for the comparison.
 CHECK_SAMPLE = 48
@@ -165,53 +165,59 @@ def _buckets(records, batch: int) -> list:
 
 
 def _bucket_traffic(buckets, rows, cols, n, degrees) -> list:
-    """Per bucket: algorithm, rows, loop trips and the stored entries in
-    each iteration's union frontier (see bench/roofline.py)."""
+    """Per bucket: algorithm, rows, loop trips and, per trip, the stored
+    entries a push would read (its algorithm's ``frontier_entries``, see
+    bench/roofline.py)."""
     out = []
     for b in buckets:
-        if b["alg"] == "bfs":
-            hops = np.stack([np.asarray(r["payload"]["levels"])
-                             for r in b["records"]])
-        else:
-            hops = reference.bfs_levels(rows, cols, n, b["roots"])
+        alg = spec.algorithm(b["alg"])
+        payloads = [r["payload"] for r in b["records"]]
         out.append({"alg": b["alg"], "rows": len(b["roots"]),
                     "trips": b["trips"],
-                    "frontier_entries": roofline.frontier_entries(
-                        hops, degrees, b["trips"])})
+                    "frontier_entries": alg.frontier_entries(
+                        payloads, rows, cols, n, b["roots"], degrees,
+                        b["trips"])})
     return out
 
 
 def compare(records, rows, cols, n, seed: int, control: bool = False) -> dict:
     """The comparison that decides ``correct``: every query sent must be
     answered, and a sample of the answers drawn from ``seed`` (which also
-    keys the SSSP weights), per algorithm, must equal the reference entry
-    for entry. With ``control`` the reference's truncated answers stand in
-    for the program's."""
+    keys any edge weights), per algorithm, must pass that algorithm's
+    ``count_wrong`` against its reference (bench/algorithms/<alg>.py). With
+    ``control`` the reference's control answers stand in for the
+    program's."""
     checks = {"unanswered": {"value": sum(not r["ok"] for r in records),
                              "limit": 0}}
     rng = np.random.default_rng([seed, 0xC4EC])
     answered = [r for r in records if r["ok"]]
-    for alg in sorted({r["alg"] for r in records}):
-        mine = [r for r in answered if r["alg"] == alg]
+    for name in sorted({r["alg"] for r in records}):
+        alg = spec.algorithm(name)
+        distance = getattr(alg, "distance", None)
+        mine = [r for r in answered if r["alg"] == name]
         if len(mine) > CHECK_SAMPLE:
             pick = rng.choice(len(mine), CHECK_SAMPLE, replace=False)
             mine = [mine[i] for i in sorted(pick)]
         roots = sorted({r["root"] for r in mine})
-        wrong = 0
+        wrong, worst = 0, 0.0
         if roots:
-            want = reference.answers(alg, rows, cols, n, roots, seed)
+            want = alg.answers(rows, cols, n, roots, seed)
             if control:
-                hops = (want if alg == "bfs"
-                        else reference.bfs_levels(rows, cols, n, roots))
-                got_by_root = dict(zip(roots, reference.truncate_last_level(
-                    alg, want, hops)))
+                got_by_root = dict(zip(roots, alg.control(
+                    rows, cols, n, roots, seed, want)))
             row_of = {root: i for i, root in enumerate(roots)}
             for r in mine:
                 got = (got_by_root[r["root"]] if control else
-                       r["payload"][reference.PAYLOAD_FIELD[alg]])
-                wrong += reference.count_wrong(alg, got, want[row_of[r["root"]]])
-        checks[f"{alg}_wrong"] = {"value": wrong, "limit": 0}
-        checks[f"{alg}_checked"] = {"value": len(mine), "limit": None}
+                       r["payload"][alg.PAYLOAD_FIELD])
+                ref = want[row_of[r["root"]]]
+                wrong += alg.count_wrong(got, ref)
+                if distance is not None:
+                    worst = max(worst, distance(got, ref))
+        checks[f"{name}_wrong"] = {"value": wrong, "limit": 0}
+        if distance is not None:
+            checks[f"{name}_{alg.DISTANCE}"] = {"value": worst,
+                                                "limit": alg.LIMIT}
+        checks[f"{name}_checked"] = {"value": len(mine), "limit": None}
     return checks
 
 
@@ -362,6 +368,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                                    default=0.0)
     out["answered_in_window"] = sum(r["ok"] and r["t_done"] <= seconds
                                     for r in records)
+    # loop trips of each device bucket the window ran, in order
+    out["bucket_trips"] = [b["trips"] for b in _buckets(records, batch)]
     out["checks"] = checks
     return out
 

@@ -8,18 +8,19 @@ and ``nnz`` stored entries can be done either way round:
   pointers, and read and write the ``[k, n]`` state at 4 bytes:
   ``nnz * (4 + value_bytes) + 4 * (n + 1) + 2 * k * n * 4``;
 * push (SpMSpV): read at least the index and value of every entry in the
-  columns of the union frontier: ``frontier_entries * (4 + value_bytes)``.
+  columns of the vertices the iteration reads from:
+  ``frontier_entries * (4 + value_bytes)``.
 
 The least traffic of the iteration is the smaller of the two, so the
-bound holds whichever kernel a later change picks. ``value_bytes`` is 0
-for BFS (its matrix is a pattern) and 4 for SSSP (float32 weights); a
-change that narrows these dtypes needs a benchmark change to recount.
+bound holds whichever kernel a later change picks. Each query kind's
+module (``bench/algorithms/<alg>.py``, found by name) says which vertices
+an iteration reads from and what ``value_bytes`` its matrix stores (0 for
+a pattern, 4 for float32 values); a change that narrows these dtypes
+needs a benchmark change to recount.
 """
 from __future__ import annotations
 
 import numpy as np
-
-VALUE_BYTES = {"bfs": 0, "sssp": 4}
 
 
 def frontier_entries(hops: np.ndarray, degrees: np.ndarray,
@@ -28,8 +29,8 @@ def frontier_entries(hops: np.ndarray, degrees: np.ndarray,
 
     ``hops`` is the bucket's ``[k, n]`` hop counts (-1 unreachable). The
     frontier read at iteration ``t`` holds every vertex first reached at
-    hop ``t``; for SSSP those are a subset of the vertices whose distance
-    changed, so the count stays a lower bound."""
+    hop ``t``; for a kind whose frontier is a superset of those (SSSP's
+    changed distances), the count stays a lower bound."""
     out = []
     for t in range(trips):
         live = np.any(hops == t, axis=0)
@@ -37,10 +38,9 @@ def frontier_entries(hops: np.ndarray, degrees: np.ndarray,
     return out
 
 
-def least_bytes(alg: str, n: int, nnz: int, k: int,
-                frontier: list) -> float:
+def least_bytes(n: int, nnz: int, k: int, frontier: list,
+                value_bytes: int) -> float:
     """Least HBM bytes of one bucket: per iteration the cheaper of pull
     and push (see the module docstring)."""
-    vb = VALUE_BYTES[alg]
-    pull = nnz * (4 + vb) + 4 * (n + 1) + 2 * k * n * 4
-    return float(sum(min(pull, e * (4 + vb)) for e in frontier))
+    pull = nnz * (4 + value_bytes) + 4 * (n + 1) + 2 * k * n * 4
+    return float(sum(min(pull, e * (4 + value_bytes)) for e in frontier))

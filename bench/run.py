@@ -34,9 +34,10 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--control", type=int, choices=(0, 1), default=0,
-                    help="1: compare the control (the reference with each "
-                         "answer's deepest level left unreached) in the "
-                         "program's place; it must come out not correct")
+                    help="1: compare the control (each algorithm's "
+                         "control answers, bench/algorithms/<alg>.py) in "
+                         "the program's place; it must come out not "
+                         "correct")
     args = ap.parse_args(argv)
     if args.seed < 0 or args.seconds <= 0:
         ap.error("--seed must be >= 0 and --seconds > 0")
@@ -58,7 +59,8 @@ def main(argv=None) -> int:
         return 1
     print(f"programs traced in the window: "
           f"{result['programs_traced_in_window']}; the sender ran at most "
-          f"{result['sender_late_s_max']:.6f} s late", file=sys.stderr)
+          f"{result['sender_late_s_max']:.6f} s late; loop trips per "
+          f"bucket: {result['bucket_trips']}", file=sys.stderr)
     harness.print_checks(result["checks"])
     print(json.dumps(result), flush=True)
     return 0

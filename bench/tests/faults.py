@@ -5,7 +5,7 @@ from __future__ import annotations
 
 import numpy as np
 
-UNREACHED = {"levels": -1, "dist": np.inf}
+UNREACHED = {"levels": -1, "dist": np.inf, "rank": 0.0}
 
 
 def state_unchanged(mp):
@@ -55,6 +55,31 @@ def answer_altered(mp):
 FAULTS = {"state_unchanged": state_unchanged,
             "half_batch_left_out": half_batch_left_out,
             "answer_altered": answer_altered}
+
+
+def state_in_bf16(mp):
+    """Every float state a traversal step reads is rounded to bfloat16, as
+    a state kept in that type would be (integer states are left alone)."""
+    import jax.numpy as jnp
+
+    from repro.graphs.engine import GraphEngine
+
+    make = GraphEngine.batch_step_fn
+
+    def batch_step_fn(self, policy):
+        step = make(self, policy)
+
+        def rounded(xs, d):
+            if jnp.issubdtype(xs.dtype, jnp.floating):
+                xs = xs.astype(jnp.bfloat16).astype(xs.dtype)
+            return step(xs, d)
+        return rounded
+
+    mp.setattr(GraphEngine, "batch_step_fn", batch_step_fn)
+
+
+# faults that only a float answer compared within a tolerance can show
+FLOAT_FAULTS = {"state_in_bf16": state_in_bf16}
 
 
 def run_tiny(workload: str, fault=None, scale: int = 8,

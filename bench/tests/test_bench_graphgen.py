@@ -3,7 +3,7 @@ seeds, and holding the Graph500 and GAP parameters."""
 import numpy as np
 import pytest
 
-from bench import graphgen
+from bench import graphgen, spec
 
 KRON = {"generator": "kronecker", "scale": 10, "edgefactor": 16,
         "structure_seed": 3,
@@ -51,7 +51,7 @@ def test_kronecker_initiator_bits():
     with probability B / (A + B) (top) or D / (C + D) (bottom)."""
     rng = np.random.default_rng(0)
     a, b, c = 0.57, 0.19, 0.19
-    src, dst = graphgen._kronecker_tuples(12, 16, a, b, c, rng)
+    src, dst = spec.generator("kronecker").bit_levels(12, 16, a, b, c, rng)
     assert src.size == 16 * 2 ** 12
     ii = (src & 1).astype(bool)
     jj = (dst & 1).astype(bool)
