@@ -44,7 +44,6 @@ from repro.graphs.multi import bfs_multi, sssp_multi
 from repro.graphs.ppr import pagerank
 from repro.serve.graph_engine import GraphQueryServer
 
-MAX_ITERS = 512        # covers every family's diameter at both scales
 PR_ITERS = 200
 
 
@@ -119,10 +118,8 @@ def run(quick: bool = False):
                             content_keyed=True)
         e0_cc = build_engine(g0, MIN_TIMES)
         e0_pr = build_engine(g0, PLUS_TIMES, normalize=True)
-        old_levels = np.asarray(bfs_multi(e0_bool, sources,
-                                          max_iters=MAX_ITERS).levels)
-        old_dist = np.asarray(sssp_multi(e0_w, sources,
-                                         max_iters=MAX_ITERS).dist)
+        old_levels = np.asarray(bfs_multi(e0_bool, sources).levels)
+        old_dist = np.asarray(sssp_multi(e0_w, sources).dist)
         old_labels = np.asarray(connected_components(e0_cc).labels)
         old_rank = np.asarray(pagerank(e0_pr, max_iters=PR_ITERS).rank)
 
@@ -141,18 +138,16 @@ def run(quick: bool = False):
             repair = plan_repair(e1_unit, d)
 
             # BFS — exactness on every batch, traffic win on grow
-            cold = bfs_multi(e1_bool, sources, max_iters=MAX_ITERS)
+            cold = bfs_multi(e1_bool, sources)
             inc = bfs_incremental(e1_unit, sources, old_levels, d,
-                                  repair=repair, max_iters=MAX_ITERS)
-            assert int(np.max(np.asarray(cold.iterations))) < MAX_ITERS
+                                  repair=repair)
             np.testing.assert_array_equal(inc.values, np.asarray(cold.levels),
                                           err_msg=f"{fam}/{kind}/bfs")
-            t_cold = timeit(lambda: bfs_multi(e1_bool, sources,
-                                              max_iters=MAX_ITERS),
+            t_cold = timeit(lambda: bfs_multi(e1_bool, sources),
                             iters=reps, warmup=1)
             t_inc = timeit(lambda: bfs_incremental(
-                e1_unit, sources, old_levels, d, repair=repair,
-                max_iters=MAX_ITERS), iters=reps, warmup=1)
+                e1_unit, sources, old_levels, d, repair=repair),
+                iters=reps, warmup=1)
             traffic_cold = traffic_of(cold)
             traffic_inc = inc.traffic + repair.traffic
             if kind == "grow":
@@ -165,18 +160,17 @@ def run(quick: bool = False):
                  checksum=_csum(np.asarray(cold.levels)))
 
             # SSSP — same bar over content-keyed integer weights
-            cold_w = sssp_multi(e1_w, sources, max_iters=MAX_ITERS)
+            cold_w = sssp_multi(e1_w, sources)
             inc_w = sssp_incremental(e1_w, sources, old_dist, d,
-                                     repair=repair, max_iters=MAX_ITERS)
+                                     repair=repair)
             np.testing.assert_array_equal(
                 inc_w.values, np.asarray(cold_w.dist),
                 err_msg=f"{fam}/{kind}/sssp")
-            t_cold = timeit(lambda: sssp_multi(e1_w, sources,
-                                               max_iters=MAX_ITERS),
+            t_cold = timeit(lambda: sssp_multi(e1_w, sources),
                             iters=reps, warmup=1)
             t_inc = timeit(lambda: sssp_incremental(
-                e1_w, sources, old_dist, d, repair=repair,
-                max_iters=MAX_ITERS), iters=reps, warmup=1)
+                e1_w, sources, old_dist, d, repair=repair),
+                iters=reps, warmup=1)
             traffic_cold = traffic_of(cold_w)
             traffic_inc = inc_w.traffic + repair.traffic
             if kind == "grow":

@@ -49,30 +49,27 @@ def _engines(g, stump):
     }
 
 
-def _sequential_fn(alg, eng, max_iters):
+def _sequential_fn(alg, eng):
     single = {"bfs": bfs, "sssp": sssp, "ppr": ppr}[alg]
-    kw = {"max_iters": max_iters} if alg != "ppr" else {}
-    return jax.jit(functools.partial(single, eng, policy="adaptive", **kw))
+    return jax.jit(functools.partial(single, eng, policy="adaptive"))
 
 
-def _batched_fn(alg, eng, batch, max_iters, mesh):
+def _batched_fn(alg, eng, batch, mesh):
     make = {"bfs": make_bfs_multi, "sssp": make_sssp_multi,
             "ppr": make_ppr_multi}[alg]
-    kw = {"max_iters": max_iters} if alg != "ppr" else {}
-    return make(eng, batch, policy="adaptive", mesh=mesh,
-                axis_name="batch", **kw)
+    return make(eng, batch, policy="adaptive", mesh=mesh, axis_name="batch")
 
 
-def bench_case(alg, eng, sources, max_iters, mesh, iters=3):
+def bench_case(alg, eng, sources, mesh, iters=3):
     b = len(sources)
-    seq = _sequential_fn(alg, eng, max_iters)
+    seq = _sequential_fn(alg, eng)
 
     def run_seq():
         return [seq(s) for s in sources]
 
     t_seq = timeit(run_seq, iters=iters, warmup=1)
 
-    batched = _batched_fn(alg, eng, b, max_iters, mesh)
+    batched = _batched_fn(alg, eng, b, mesh)
     src = jnp.asarray(np.asarray(sources), jnp.int32)
     t_bat = timeit(batched, src, iters=iters, warmup=1)
 
@@ -95,7 +92,7 @@ def run(quick: bool = False, batch: int = 8, shard: bool = False):
         sources = [int(s) for s in rng.integers(0, g.n, batch)]
         for alg in ("bfs", "sssp", "ppr"):
             qps_seq, qps_bat, speedup = bench_case(
-                alg, engines[alg], sources, max_iters=64, mesh=mesh)
+                alg, engines[alg], sources, mesh=mesh)
             speedups.append(speedup)
             emit("multi_query", f"{ds}/{alg}",
                  n=g.n, nnz=g.nnz, batch=batch, devices=n_dev,

@@ -321,13 +321,11 @@ def four_chip_phase(g, roots) -> None:
     log(f"four-chip served bfs: sharded flush_s="
         f"{time.perf_counter() - t0:.3f} (host wall clock)")
     eng = srv.engine("bfs")
-    sharded = bfs_multi(eng, sources, max_iters=srv.max_iters,
-                        policy=srv.policy, mesh=batch_mesh)
+    sharded = bfs_multi(eng, sources, policy=srv.policy, mesh=batch_mesh)
     log(f"four-chip served bfs: levels [B, n] bytes per device "
         f"{_per_device_bytes(sharded.levels)}")
     # the same engine and runner, the query block on one device
-    single = bfs_multi(eng, sources, max_iters=srv.max_iters,
-                       policy=srv.policy)
+    single = bfs_multi(eng, sources, policy=srv.policy)
     for i, req in enumerate(reqs):
         np.testing.assert_array_equal(req.result["levels"],
                                       np.asarray(single.levels[i]))

@@ -27,9 +27,12 @@ def main():
     res = bfs(eng, src, policy="adaptive")
     print(f"\nBFS from {src}: {int(res.iterations)} levels, "
           f"{int((np.asarray(res.levels) >= 0).sum())}/{g.n} reached")
-    for it in range(int(res.iterations)):
-        d = float(res.densities[it])
-        k = "SpMV  " if int(res.kernel_used[it]) else "SpMSpV"
+    print(f"  {int(res.spmv_trips)} of {int(res.iterations)} trips on the "
+          f"SpMV, the rest on the SpMSpV")
+    levels = np.asarray(res.levels)
+    for it, size in enumerate(np.bincount(levels[levels >= 0])):
+        d = size / g.n
+        k = "SpMV  " if d > eng.threshold else "SpMSpV"
         print(f"  level {it:2d}: density={d:6.1%}  kernel={k}")
 
     eng = build_engine(g, MIN_PLUS, stump, weighted=True)

@@ -17,7 +17,7 @@ class GraphRunConfig:
     fmt: str = "csc"                  # coo | csr | csc
     adaptive: bool = True             # SpMSpV <-> SpMV switching (paper §4.2)
     block: Tuple[int, int] = (128, 128)   # BSR tile (MXU-aligned)
-    max_iters: int = 64
+    max_iters: int = 64               # PPR and PageRank; BFS, SSSP: fixpoint
     ppr_alpha: float = 0.85
     scale: float = 0.05               # dataset scale factor for CPU runs
 

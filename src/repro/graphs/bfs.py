@@ -4,7 +4,9 @@ Level-synchronous pull BFS: fₖ₊₁ = (Aᵀ ⊕.⊗ fₖ) ∧ ¬visited. The 
 density is monitored every level; the adaptive policy switches SpMSpV→SpMV
 once it crosses the decision-tree threshold (§4.2) — all inside one jitted
 `lax.while_loop` (`lax.cond` makes the switch free, unlike UPMEM's
-host-side check).
+host-side check). The loop runs until the frontier is empty, at any depth,
+and counts its trips, those on the SpMV and the frontier nonzeros read, as
+the batched runners (graphs/multi.py) count each row's.
 """
 from __future__ import annotations
 
@@ -15,19 +17,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.semiring import BOOL_OR_AND
-from repro.graphs.engine import GraphEngine, density_of
+from repro.graphs.engine import GraphEngine, nonzeros_of
 
 Array = jax.Array
 
 
 class BFSResult(NamedTuple):
     levels: Array       # int32 [n]; -1 = unreached
-    iterations: Array   # scalar int32
-    densities: Array    # f32 [max_iters] frontier density trace (Fig 4)
-    kernel_used: Array  # int32 [max_iters]; 0 = SpMSpV, 1 = SpMV, -1 = unused
+    iterations: Array   # scalar int32: loop trips
+    spmv_trips: Array   # scalar int32: of those, trips on the SpMV
+    consumed: Array     # scalar int32: frontier nonzeros read over all trips
 
 
-def bfs(engine: GraphEngine, source: int, max_iters: int = 64,
+def bfs(engine: GraphEngine, source: int,
         policy: str = "adaptive") -> BFSResult:
     sr = engine.sr
     assert sr.name == BOOL_OR_AND.name
@@ -35,12 +37,13 @@ def bfs(engine: GraphEngine, source: int, max_iters: int = 64,
     step = engine.step_fn(policy)
 
     def cond(state):
-        frontier, visited, levels, it, done, dens, kern = state
-        return (~done) & (it < max_iters)
+        frontier, visited, levels, it, done, spmv, consumed = state
+        return (~done) & (it < engine.n_true)
 
     def body(state):
-        frontier, visited, levels, it, done, dens, kern = state
-        density = density_of(frontier, sr, engine.n_true)
+        frontier, visited, levels, it, done, spmv, consumed = state
+        nz = nonzeros_of(frontier, sr, engine.n_true)
+        density = nz.astype(jnp.float32) / float(engine.n_true)
         used = jnp.where(policy == "spmv", 1,
                          jnp.where(policy == "spmspv", 0,
                                    (density > engine.threshold).astype(jnp.int32)))
@@ -50,20 +53,18 @@ def bfs(engine: GraphEngine, source: int, max_iters: int = 64,
         levels = jnp.where((new_frontier != 0) & (levels < 0), it + 1, levels)
         visited = jnp.where(new_frontier != 0, 1, visited)
         done = jnp.sum(new_frontier) == 0
-        dens = dens.at[it].set(density)
-        kern = kern.at[it].set(used)
-        return (new_frontier, visited, levels, it + 1, done, dens, kern)
+        return (new_frontier, visited, levels, it + 1, done, spmv + used,
+                consumed + nz)
 
     frontier0 = jnp.zeros((n,), sr.dtype).at[source].set(1)
     visited0 = jnp.zeros((n,), jnp.int32).at[source].set(1)
     levels0 = jnp.full((n,), -1, jnp.int32).at[source].set(0)
-    dens0 = jnp.full((max_iters,), -1.0, jnp.float32)
-    kern0 = jnp.full((max_iters,), -1, jnp.int32)
+    zero = jnp.asarray(0, jnp.int32)
 
-    frontier, visited, levels, it, done, dens, kern = jax.lax.while_loop(
-        cond, body, (frontier0, visited0, levels0, jnp.asarray(0, jnp.int32),
-                     jnp.asarray(False), dens0, kern0))
-    return BFSResult(levels[: engine.n_true], it, dens, kern)
+    frontier, visited, levels, it, done, spmv, consumed = jax.lax.while_loop(
+        cond, body, (frontier0, visited0, levels0, zero,
+                     jnp.asarray(False), zero, zero))
+    return BFSResult(levels[: engine.n_true], it, spmv, consumed)
 
 
 def bfs_reference(rows: np.ndarray, cols: np.ndarray, n: int, source: int) -> np.ndarray:

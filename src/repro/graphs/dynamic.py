@@ -96,19 +96,10 @@ class DynamicGraph:
 
 def traffic_of(result) -> float:
     """Element traffic of one batched traversal: frontier nonzeros the
-    kernel consumed, summed over queries and iterations (densities trace
-    × true vertex count — the Load-phase element accounting of
+    kernel consumed, summed over queries and iterations (the runner's
+    per-row ``consumed`` count — the Load-phase element accounting of
     core/distributed.py, applied to the single-device path)."""
-    dens = np.asarray(result.densities, np.float64)
-    n_true = None
-    # the [B, n_true] payload axis carries the vertex count
-    for field in ("levels", "dist", "rank"):
-        arr = getattr(result, field, None)
-        if arr is not None:
-            n_true = arr.shape[-1]
-            break
-    assert n_true is not None, "result carries no per-vertex payload"
-    return float(np.sum(np.where(dens >= 0, dens, 0.0)) * n_true)
+    return float(np.sum(np.asarray(result.consumed, np.float64)))
 
 
 class DeltaRepair(NamedTuple):
@@ -120,8 +111,7 @@ class DeltaRepair(NamedTuple):
     traffic: float             # reachability-pass element traffic
 
 
-def plan_repair(engine: GraphEngine, delta: EdgeDelta,
-                max_iters: int | None = None) -> DeltaRepair:
+def plan_repair(engine: GraphEngine, delta: EdgeDelta) -> DeltaRepair:
     """Compute the delta's repair plan against the **new** snapshot's
     ⟨min,+⟩ engine (unit or weighted — only finiteness is read).
 
@@ -142,23 +132,23 @@ def plan_repair(engine: GraphEngine, delta: EdgeDelta,
     seeds = np.unique(np.concatenate([delta.delete_rows, delta.delete_cols]))
     d0 = np.full((1, n_true), np.inf, np.float32)
     d0[0, seeds] = 0.0
-    # the reach pass must run to fixpoint (a truncated stale set would
-    # leave invalid distances in place) — cap at n_true, the hop bound
-    res = relax_multi(engine, d0, d0.copy(), max_iters=max_iters or n_true)
+    # the reach pass runs to its fixpoint (a truncated stale set would
+    # leave invalid distances in place), as every relax does
+    res = relax_multi(engine, d0, d0.copy())
     stale = np.isfinite(np.asarray(res.dist[0]))
     return DeltaRepair(touched, stale, traffic_of(res))
 
 
 class IncrementalTraversal(NamedTuple):
     values: np.ndarray         # levels int32 / dist f32, [B, n_true]
-    result: SSSPBatchResult    # the relax result (iterations, traces)
+    result: SSSPBatchResult    # the relax result (per-row counters)
     traffic: float             # relax traffic (excl. the shared repair pass)
     repair: DeltaRepair
 
 
 def _incremental_relax(engine: GraphEngine, sources, old_dist: np.ndarray,
                        delta: EdgeDelta, repair: DeltaRepair | None,
-                       max_iters: int, policy: str) -> IncrementalTraversal:
+                       policy: str) -> IncrementalTraversal:
     """Shared BFS/SSSP delta-frontier re-relaxation: reset the stale
     region, restore the sources' zeros, seed ``changed`` from the touched
     vertices plus the stale region, relax to fixpoint."""
@@ -179,16 +169,14 @@ def _incremental_relax(engine: GraphEngine, sources, old_dist: np.ndarray,
     d0[rows, src] = 0.0          # the source is correct in every epoch
     changed0 = np.where(seed[None, :] & np.isfinite(d0), d0,
                         np.float32(np.inf)).astype(np.float32)
-    res = relax_multi(engine, d0, changed0, max_iters=max_iters,
-                      policy=policy)
+    res = relax_multi(engine, d0, changed0, policy=policy)
     dist = np.asarray(res.dist)
     return IncrementalTraversal(dist, res, traffic_of(res), repair)
 
 
 def sssp_incremental(engine: GraphEngine, sources, old_dist,
                      delta: EdgeDelta, repair: DeltaRepair | None = None,
-                     max_iters: int = 64, policy: str = "adaptive"
-                     ) -> IncrementalTraversal:
+                     policy: str = "adaptive") -> IncrementalTraversal:
     """Incremental SSSP: ``old_dist`` [B, n_true] from the previous
     snapshot (+inf = unreachable), ``engine`` a **content-keyed** weighted
     ⟨min,+⟩ engine over the new snapshot. Element-equal to a cold
@@ -197,13 +185,12 @@ def sssp_incremental(engine: GraphEngine, sources, old_dist,
     the ⟨min,+⟩ fixpoint over integer-valued weights is unique and exact
     in f32 (tests/test_dynamic.py, benchmarks/dynamic_updates.py)."""
     return _incremental_relax(engine, sources, old_dist, delta, repair,
-                              max_iters, policy)
+                              policy)
 
 
 def bfs_incremental(engine: GraphEngine, sources, old_levels,
                     delta: EdgeDelta, repair: DeltaRepair | None = None,
-                    max_iters: int = 64, policy: str = "adaptive"
-                    ) -> IncrementalTraversal:
+                    policy: str = "adaptive") -> IncrementalTraversal:
     """Incremental BFS as unit-weight incremental SSSP: ``old_levels``
     [B, n_true] int (-1 = unreached) from the previous snapshot,
     ``engine`` a unit-weight ⟨min,+⟩ engine (build_engine(g, MIN_PLUS,
@@ -214,7 +201,7 @@ def bfs_incremental(engine: GraphEngine, sources, old_levels,
     old_dist = np.where(lev < 0, np.float32(np.inf),
                         lev.astype(np.float32))
     out = _incremental_relax(engine, sources, old_dist, delta, repair,
-                             max_iters, policy)
+                             policy)
     levels = np.where(np.isfinite(out.values),
                       out.values, -1.0).astype(np.int32)
     return IncrementalTraversal(levels, out.result, out.traffic, out.repair)

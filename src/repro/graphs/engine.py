@@ -328,12 +328,15 @@ def _pad_cols(xs: Array, n: int, sr: Semiring) -> Array:
                    constant_values=sr.zero)
 
 
+def nonzeros_of(x: Array, sr: Semiring, n_true: int) -> Array:
+    """Frontier nonzeros of an [n] vector -> scalar int32."""
+    return jnp.sum((x[:n_true] != sr.zero).astype(jnp.int32))
+
+
 def density_of(x: Array, sr: Semiring, n_true: int) -> Array:
-    nz = jnp.sum((x[:n_true] != sr.zero).astype(jnp.int32))
-    return nz.astype(jnp.float32) / float(n_true)
+    return nonzeros_of(x, sr, n_true).astype(jnp.float32) / float(n_true)
 
 
-def density_of_batch(xs: Array, sr: Semiring, n_true: int) -> Array:
-    """Per-row frontier densities of a [B, n] block -> [B] f32."""
-    nz = jnp.sum((xs[:, :n_true] != sr.zero).astype(jnp.int32), axis=1)
-    return nz.astype(jnp.float32) / float(n_true)
+def nonzeros_of_batch(xs: Array, sr: Semiring, n_true: int) -> Array:
+    """Per-row frontier nonzeros of a [B, n] block -> [B] int32."""
+    return jnp.sum((xs[:, :n_true] != sr.zero).astype(jnp.int32), axis=1)

@@ -5,10 +5,18 @@ regime the ROADMAP serves).
 One ``lax.while_loop`` advances all B queries in lockstep; per-query
 adaptive SpMSpV↔SpMV switching happens as data flow (see
 core.adaptive.adaptive_matvec_batch), and a query that converges is frozen
-— its state rows stop updating and its trace stops recording — so every
+— its state rows stop updating and its counters stop counting — so every
 row of the batched result is element-equal to the corresponding
 single-source run (asserted in tests/test_multi_query.py, including the
-kernel-choice trace and per-query iteration counts).
+per-query iteration counts and kernel choices).
+
+BFS and SSSP run to their fixpoint: a row stops when its frontier is
+empty, and the only bound is the one the graph implies (a relaxation
+settles within ``n_true - 1`` rounds), so no answer is ever cut short,
+however deep the graph. PPR keeps ``max_iters`` as part of its stopping
+rule. The loop carries the ``[B, n]`` state and ``[B]`` counters per row
+(trips, trips on the SpMV, frontier entries read), never an array that
+grows with the number of trips.
 
 ``mesh``/``axis_name`` shard the [B, n] block over devices: queries are
 independent, so the block row-shards with no cross-device traffic beyond
@@ -41,30 +49,31 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.adaptive import select_kernel_batch
 from repro.core.pipeline import pipeline_buckets
 from repro.core.semiring import BOOL_OR_AND, MIN_PLUS, PLUS_TIMES
-from repro.graphs.engine import GraphEngine, density_of_batch
+from repro.graphs.engine import GraphEngine, nonzeros_of_batch
 
 Array = jax.Array
 
 
 class BFSBatchResult(NamedTuple):
     levels: Array       # int32 [B, n_true]; -1 = unreached
-    iterations: Array   # int32 [B]
-    densities: Array    # f32 [B, max_iters]
-    kernel_used: Array  # int32 [B, max_iters]; 0 = SpMSpV, 1 = SpMV, -1 unused
+    iterations: Array   # int32 [B]: loop trips the row ran
+    spmv_trips: Array   # int32 [B]: of those, trips on the SpMV (the rest
+    #                     ran the SpMSpV ladder)
+    consumed: Array     # int32 [B]: frontier nonzeros read over all trips
 
 
 class SSSPBatchResult(NamedTuple):
     dist: Array         # f32 [B, n_true]; +inf = unreachable
     iterations: Array
-    densities: Array
-    kernel_used: Array
+    spmv_trips: Array
+    consumed: Array
 
 
 class PPRBatchResult(NamedTuple):
     rank: Array         # f32 [B, n_true]
     iterations: Array
-    densities: Array
-    kernel_used: Array
+    spmv_trips: Array
+    consumed: Array
     residual: Array     # f32 [B]
 
 
@@ -85,10 +94,26 @@ def _constrain_block(x: Array, mesh: Mesh | None, axis_name: str) -> Array:
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def _masked_trace_update(trace: Array, it: Array, active: Array,
-                         value: Array) -> Array:
-    """trace[:, it] = value where the query is still active."""
-    return trace.at[:, it].set(jnp.where(active, value, trace[:, it]))
+def _tally0(b: int) -> tuple:
+    """Per-row (iterations, spmv_trips, consumed), all zero."""
+    return (jnp.zeros((b,), jnp.int32),) * 3
+
+
+def _tally(tally: tuple, it: Array, active: Array, used: Array,
+           nz: Array) -> tuple:
+    """Count trip ``it`` in the rows still active: one more iteration, on
+    the SpMV where ``used`` is 1, reading ``nz`` frontier nonzeros."""
+    iters, spmv_trips, consumed = tally
+    return (jnp.where(active, it + 1, iters),
+            spmv_trips + (active & (used == 1)).astype(jnp.int32),
+            consumed + jnp.where(active, nz, 0))
+
+
+def _frontier_stats(engine: GraphEngine, policy: str, xs: Array):
+    """(density [B], kernel codes [B], nonzeros [B]) of a frontier block."""
+    nz = nonzeros_of_batch(xs, engine.sr, engine.n_true)
+    density = nz.astype(jnp.float32) / float(engine.n_true)
+    return density, _kernel_codes(policy, density, engine.threshold), nz
 
 
 class BatchRunner:
@@ -112,11 +137,12 @@ class BatchRunner:
         return self.jitted(self.mats, *args)
 
 
-def make_bfs_multi(engine: GraphEngine, batch: int, max_iters: int = 64,
+def make_bfs_multi(engine: GraphEngine, batch: int,
                    policy: str = "adaptive", mesh: Mesh | None = None,
                    axis_name: str = "batch"
                    ) -> Callable[[Array], BFSBatchResult]:
-    """Build a jitted runner: sources [B] int32 -> BFSBatchResult."""
+    """Build a jitted runner: sources [B] int32 -> BFSBatchResult. Each
+    row runs until its frontier is empty."""
     sr = engine.sr
     assert sr.name == BOOL_OR_AND.name
     n, b = engine.n, batch
@@ -132,15 +158,14 @@ def make_bfs_multi(engine: GraphEngine, batch: int, max_iters: int = 64,
         levels = _constrain_block(levels, mesh, axis_name)
 
         def cond(state):
-            _f, _v, _l, it, done, _its, _d, _k = state
-            return (~jnp.all(done)) & (it < max_iters)
+            _f, _v, _l, it, done, _t = state
+            return (~jnp.all(done)) & (it < engine.n_true)
 
         @jax.named_scope("bfs_step")
         def body(state):
-            frontier, visited, levels, it, done, iters, dens, kern = state
+            frontier, visited, levels, it, done, tally = state
             active = ~done
-            density = density_of_batch(frontier, sr, engine.n_true)
-            used = _kernel_codes(policy, density, engine.threshold)
+            density, used, nz = _frontier_stats(engine, policy, frontier)
             y = step(frontier, density)
             nf = jnp.where((y != sr.zero) & (visited == 0),
                            jnp.asarray(1, sr.dtype), jnp.asarray(0, sr.dtype))
@@ -148,46 +173,40 @@ def make_bfs_multi(engine: GraphEngine, batch: int, max_iters: int = 64,
             levels = jnp.where((nf != 0) & (levels < 0), it + 1, levels)
             visited = jnp.where(nf != 0, 1, visited)
             newly_done = jnp.sum(nf, axis=1) == 0
-            iters = jnp.where(active, it + 1, iters)
-            dens = _masked_trace_update(dens, it, active, density)
-            kern = _masked_trace_update(kern, it, active, used)
             return (nf, visited, levels, it + 1, done | newly_done,
-                    iters, dens, kern)
+                    _tally(tally, it, active, used, nz))
 
         state0 = (frontier, visited, levels, jnp.asarray(0, jnp.int32),
-                  jnp.zeros((b,), bool), jnp.zeros((b,), jnp.int32),
-                  jnp.full((b, max_iters), -1.0, jnp.float32),
-                  jnp.full((b, max_iters), -1, jnp.int32))
-        _f, _v, levels, _it, _done, iters, dens, kern = jax.lax.while_loop(
+                  jnp.zeros((b,), bool), _tally0(b))
+        _f, _v, levels, _it, _done, tally = jax.lax.while_loop(
             cond, body, state0)
-        return BFSBatchResult(levels[:, : engine.n_true], iters, dens, kern)
+        return BFSBatchResult(levels[:, : engine.n_true], *tally)
 
     return BatchRunner(engine, run, mesh)
 
 
-def _relax_block(engine: GraphEngine, policy: str, max_iters: int,
-                 dist: Array, changed: Array) -> SSSPBatchResult:
+def _relax_block(engine: GraphEngine, policy: str, dist: Array,
+                 changed: Array) -> SSSPBatchResult:
     """The ⟨min,+⟩ re-relaxation loop over a [B, n] state block, shared by
     the cold-start SSSP runner and the warm-start resume runner: relax
     only from rows' ``changed`` frontiers until no distance improves.
     Any (dist, changed) with dist ≥ the true fixpoint pointwise and every
     possible improvement reachable from a changed vertex converges to the
     exact fixpoint — the property graphs/dynamic.py's incremental
-    recompute is built on."""
-    sr = engine.sr
+    recompute is built on. With positive weights a row's changed set
+    empties within ``n_true`` rounds, so that bound never cuts one short."""
     b = dist.shape[0]
     step = engine.batch_step_fn(policy)
 
     def cond(state):
-        _di, _ch, it, done, _its, _d, _k = state
-        return (~jnp.all(done)) & (it < max_iters)
+        _di, _ch, it, done, _t = state
+        return (~jnp.all(done)) & (it < engine.n_true)
 
     @jax.named_scope("sssp_step")
     def body(state):
-        dist, changed, it, done, iters, dens, kern = state
+        dist, changed, it, done, tally = state
         active = ~done
-        density = density_of_batch(changed, sr, engine.n_true)
-        used = _kernel_codes(policy, density, engine.threshold)
+        density, used, nz = _frontier_stats(engine, policy, changed)
         cand = step(changed, density)
         new_dist = jnp.minimum(dist, cand)
         new_changed = jnp.where(new_dist < dist, new_dist, jnp.inf)
@@ -196,22 +215,16 @@ def _relax_block(engine: GraphEngine, policy: str, max_iters: int,
                                 jnp.full_like(new_changed, jnp.inf))
         newly_done = jnp.sum((new_changed != jnp.inf).astype(jnp.int32),
                              axis=1) == 0
-        iters = jnp.where(active, it + 1, iters)
-        dens = _masked_trace_update(dens, it, active, density)
-        kern = _masked_trace_update(kern, it, active, used)
         return (new_dist, new_changed, it + 1, done | newly_done,
-                iters, dens, kern)
+                _tally(tally, it, active, used, nz))
 
     state0 = (dist, changed, jnp.asarray(0, jnp.int32),
-              jnp.zeros((b,), bool), jnp.zeros((b,), jnp.int32),
-              jnp.full((b, max_iters), -1.0, jnp.float32),
-              jnp.full((b, max_iters), -1, jnp.int32))
-    dist, _ch, _it, _done, iters, dens, kern = jax.lax.while_loop(
-        cond, body, state0)
-    return SSSPBatchResult(dist[:, : engine.n_true], iters, dens, kern)
+              jnp.zeros((b,), bool), _tally0(b))
+    dist, _ch, _it, _done, tally = jax.lax.while_loop(cond, body, state0)
+    return SSSPBatchResult(dist[:, : engine.n_true], *tally)
 
 
-def make_sssp_multi(engine: GraphEngine, batch: int, max_iters: int = 64,
+def make_sssp_multi(engine: GraphEngine, batch: int,
                     policy: str = "adaptive", mesh: Mesh | None = None,
                     axis_name: str = "batch"
                     ) -> Callable[[Array], SSSPBatchResult]:
@@ -227,12 +240,12 @@ def make_sssp_multi(engine: GraphEngine, batch: int, max_iters: int = 64,
                            ).at[rows, sources].set(0.0)
         dist = _constrain_block(dist, mesh, axis_name)
         changed = _constrain_block(changed, mesh, axis_name)
-        return _relax_block(engine, policy, max_iters, dist, changed)
+        return _relax_block(engine, policy, dist, changed)
 
     return BatchRunner(engine, run, mesh)
 
 
-def make_relax_multi(engine: GraphEngine, batch: int, max_iters: int = 64,
+def make_relax_multi(engine: GraphEngine, batch: int,
                      policy: str = "adaptive", mesh: Mesh | None = None,
                      axis_name: str = "batch"
                      ) -> Callable[[Array, Array], SSSPBatchResult]:
@@ -254,7 +267,7 @@ def make_relax_multi(engine: GraphEngine, batch: int, max_iters: int = 64,
         changed = jnp.pad(changed0, pad, constant_values=jnp.inf)
         dist = _constrain_block(dist, mesh, axis_name)
         changed = _constrain_block(changed, mesh, axis_name)
-        return _relax_block(engine, policy, max_iters, dist, changed)
+        return _relax_block(engine, policy, dist, changed)
 
     return BatchRunner(engine, run, mesh)
 
@@ -276,32 +289,25 @@ def make_ppr_multi(engine: GraphEngine, batch: int, alpha: float = 0.85,
         e_s = _constrain_block(e_s, mesh, axis_name)
 
         def cond(state):
-            _r, it, res, _its, _d, _k = state
+            _r, it, res, _t = state
             return jnp.any(res > tol) & (it < max_iters)
 
         @jax.named_scope("ppr_step")
         def body(state):
-            r, it, res, iters, dens, kern = state
+            r, it, res, tally = state
             active = res > tol
-            density = density_of_batch(r, sr, engine.n_true)
-            used = _kernel_codes(policy, density, engine.threshold)
+            density, used, nz = _frontier_stats(engine, policy, r)
             pr = step(r, density)
             r_new = (1.0 - alpha) * e_s + alpha * pr
             res_new = jnp.sum(jnp.abs(r_new - r), axis=1)
             r = jnp.where(active[:, None], r_new, r)
             res = jnp.where(active, res_new, res)
-            iters = jnp.where(active, it + 1, iters)
-            dens = _masked_trace_update(dens, it, active, density)
-            kern = _masked_trace_update(kern, it, active, used)
-            return (r, it + 1, res, iters, dens, kern)
+            return (r, it + 1, res, _tally(tally, it, active, used, nz))
 
         state0 = (e_s, jnp.asarray(0, jnp.int32),
-                  jnp.full((b,), jnp.inf, jnp.float32),
-                  jnp.zeros((b,), jnp.int32),
-                  jnp.full((b, max_iters), -1.0, jnp.float32),
-                  jnp.full((b, max_iters), -1, jnp.int32))
-        r, _it, res, iters, dens, kern = jax.lax.while_loop(cond, body, state0)
-        return PPRBatchResult(r[:, : engine.n_true], iters, dens, kern, res)
+                  jnp.full((b,), jnp.inf, jnp.float32), _tally0(b))
+        r, _it, res, tally = jax.lax.while_loop(cond, body, state0)
+        return PPRBatchResult(r[:, : engine.n_true], *tally, res)
 
     return BatchRunner(engine, run, mesh)
 
@@ -335,27 +341,27 @@ def _as_sources(sources) -> Array:
     return src
 
 
-def bfs_multi(engine: GraphEngine, sources, max_iters: int = 64,
-              policy: str = "adaptive", mesh: Mesh | None = None,
+def bfs_multi(engine: GraphEngine, sources, policy: str = "adaptive",
+              mesh: Mesh | None = None,
               axis_name: str = "batch") -> BFSBatchResult:
     """Multi-source BFS; row b equals bfs(engine, sources[b])."""
     src = _as_sources(sources)
     run = _cached_runner(engine, "bfs", int(src.shape[0]), mesh, axis_name,
-                         max_iters=max_iters, policy=policy)
+                         policy=policy)
     return run(src)
 
 
-def sssp_multi(engine: GraphEngine, sources, max_iters: int = 64,
-               policy: str = "adaptive", mesh: Mesh | None = None,
+def sssp_multi(engine: GraphEngine, sources, policy: str = "adaptive",
+               mesh: Mesh | None = None,
                axis_name: str = "batch") -> SSSPBatchResult:
     """Multi-source SSSP; row b equals sssp(engine, sources[b])."""
     src = _as_sources(sources)
     run = _cached_runner(engine, "sssp", int(src.shape[0]), mesh, axis_name,
-                         max_iters=max_iters, policy=policy)
+                         policy=policy)
     return run(src)
 
 
-def relax_multi(engine: GraphEngine, dist0, changed0, max_iters: int = 64,
+def relax_multi(engine: GraphEngine, dist0, changed0,
                 policy: str = "adaptive", mesh: Mesh | None = None,
                 axis_name: str = "batch") -> SSSPBatchResult:
     """Warm-start ⟨min,+⟩ re-relaxation from explicit [B, n_true] state
@@ -367,7 +373,7 @@ def relax_multi(engine: GraphEngine, dist0, changed0, max_iters: int = 64,
     c0 = jnp.asarray(np.asarray(changed0, np.float32))
     assert d0.ndim == 2 and d0.shape == c0.shape, (d0.shape, c0.shape)
     run = _cached_runner(engine, "relax", int(d0.shape[0]), mesh, axis_name,
-                         max_iters=max_iters, policy=policy)
+                         policy=policy)
     return run(d0, c0)
 
 
@@ -391,7 +397,7 @@ def traverse_multi_buckets(engine: GraphEngine, alg: str, buckets,
     sequential drain; results are identical at any depth — the same
     jitted runner consumes the same buckets, only host sync order changes
     (asserted in tests/test_multi_query.py). ``kwargs`` are the
-    per-algorithm maker options (max_iters / policy / alpha / tol).
+    per-algorithm maker options (policy; PPR's alpha / max_iters / tol).
     Returns one materialised value per bucket, in submission order.
     """
     def issue(bucket):

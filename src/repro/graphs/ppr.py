@@ -15,16 +15,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.semiring import PLUS_TIMES
-from repro.graphs.engine import GraphEngine, density_of
+from repro.graphs.engine import GraphEngine, nonzeros_of
 
 Array = jax.Array
 
 
 class PPRResult(NamedTuple):
     rank: Array
-    iterations: Array
-    densities: Array
-    kernel_used: Array
+    iterations: Array   # scalar int32: loop trips
+    spmv_trips: Array   # scalar int32: of those, trips on the SpMV
+    consumed: Array     # scalar int32: rank-vector nonzeros read
     residual: Array
 
 
@@ -38,28 +38,25 @@ def ppr(engine: GraphEngine, source: int, alpha: float = 0.85,
     e_s = jnp.zeros((n,), jnp.float32).at[source].set(1.0)
 
     def cond(state):
-        r, it, res, dens, kern = state
+        r, it, res, spmv, consumed = state
         return (res > tol) & (it < max_iters)
 
     def body(state):
-        r, it, res, dens, kern = state
-        density = density_of(r, sr, engine.n_true)
+        r, it, res, spmv, consumed = state
+        nz = nonzeros_of(r, sr, engine.n_true)
+        density = nz.astype(jnp.float32) / float(engine.n_true)
         used = jnp.where(policy == "spmv", 1,
                          jnp.where(policy == "spmspv", 0,
                                    (density > engine.threshold).astype(jnp.int32)))
         pr = step(r, density)
         r_new = (1.0 - alpha) * e_s + alpha * pr
         res = jnp.sum(jnp.abs(r_new - r))
-        dens = dens.at[it].set(density)
-        kern = kern.at[it].set(used)
-        return (r_new, it + 1, res, dens, kern)
+        return (r_new, it + 1, res, spmv + used, consumed + nz)
 
-    dens0 = jnp.full((max_iters,), -1.0, jnp.float32)
-    kern0 = jnp.full((max_iters,), -1, jnp.int32)
-    r, it, res, dens, kern = jax.lax.while_loop(
-        cond, body, (e_s, jnp.asarray(0, jnp.int32), jnp.asarray(jnp.inf),
-                     dens0, kern0))
-    return PPRResult(r[: engine.n_true], it, dens, kern, res)
+    zero = jnp.asarray(0, jnp.int32)
+    r, it, res, spmv, consumed = jax.lax.while_loop(
+        cond, body, (e_s, zero, jnp.asarray(jnp.inf), zero, zero))
+    return PPRResult(r[: engine.n_true], it, spmv, consumed, res)
 
 
 def pagerank(engine: GraphEngine, alpha: float = 0.85, max_iters: int = 50,
@@ -88,28 +85,25 @@ def pagerank(engine: GraphEngine, alpha: float = 0.85, max_iters: int = 50,
         start = jnp.pad(r0, (0, n - engine.n_true))
 
     def cond(state):
-        r, it, res, dens, kern = state
+        r, it, res, spmv, consumed = state
         return (res > tol) & (it < max_iters)
 
     def body(state):
-        r, it, res, dens, kern = state
-        density = density_of(r, sr, engine.n_true)
+        r, it, res, spmv, consumed = state
+        nz = nonzeros_of(r, sr, engine.n_true)
+        density = nz.astype(jnp.float32) / float(engine.n_true)
         used = jnp.where(policy == "spmv", 1,
                          jnp.where(policy == "spmspv", 0,
                                    (density > engine.threshold).astype(jnp.int32)))
         pr = step(r, density)
         r_new = (1.0 - alpha) * e + alpha * pr
         res = jnp.sum(jnp.abs(r_new - r))
-        dens = dens.at[it].set(density)
-        kern = kern.at[it].set(used)
-        return (r_new, it + 1, res, dens, kern)
+        return (r_new, it + 1, res, spmv + used, consumed + nz)
 
-    dens0 = jnp.full((max_iters,), -1.0, jnp.float32)
-    kern0 = jnp.full((max_iters,), -1, jnp.int32)
-    r, it, res, dens, kern = jax.lax.while_loop(
-        cond, body, (start, jnp.asarray(0, jnp.int32), jnp.asarray(jnp.inf),
-                     dens0, kern0))
-    return PPRResult(r[: engine.n_true], it, dens, kern, res)
+    zero = jnp.asarray(0, jnp.int32)
+    r, it, res, spmv, consumed = jax.lax.while_loop(
+        cond, body, (start, zero, jnp.asarray(jnp.inf), zero, zero))
+    return PPRResult(r[: engine.n_true], it, spmv, consumed, res)
 
 
 def pagerank_reference(rows: np.ndarray, cols: np.ndarray, n: int,

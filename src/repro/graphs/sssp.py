@@ -3,7 +3,9 @@
 Bellman-Ford with frontier pruning: each iteration relaxes only from
 vertices whose distance changed last round (the sparse frontier), i.e.
 cand = Aᵀ ⊕.⊗ changed, dist' = min(dist, cand). The changed-set density
-drives the adaptive SpMSpV↔SpMV switch exactly as in BFS.
+drives the adaptive SpMSpV↔SpMV switch exactly as in BFS. The loop runs
+until no distance improves (within ``n_true`` rounds for positive
+weights) and counts its trips as BFS does.
 """
 from __future__ import annotations
 
@@ -14,19 +16,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.semiring import MIN_PLUS
-from repro.graphs.engine import GraphEngine, density_of
+from repro.graphs.engine import GraphEngine, nonzeros_of
 
 Array = jax.Array
 
 
 class SSSPResult(NamedTuple):
     dist: Array         # f32 [n]; +inf = unreachable
-    iterations: Array
-    densities: Array
-    kernel_used: Array
+    iterations: Array   # scalar int32: loop trips
+    spmv_trips: Array   # scalar int32: of those, trips on the SpMV
+    consumed: Array     # scalar int32: changed-set nonzeros read
 
 
-def sssp(engine: GraphEngine, source: int, max_iters: int = 64,
+def sssp(engine: GraphEngine, source: int,
          policy: str = "adaptive") -> SSSPResult:
     sr = engine.sr
     assert sr.name == MIN_PLUS.name
@@ -34,12 +36,13 @@ def sssp(engine: GraphEngine, source: int, max_iters: int = 64,
     step = engine.step_fn(policy)
 
     def cond(state):
-        dist, changed, it, done, dens, kern = state
-        return (~done) & (it < max_iters)
+        dist, changed, it, done, spmv, consumed = state
+        return (~done) & (it < engine.n_true)
 
     def body(state):
-        dist, changed, it, done, dens, kern = state
-        density = density_of(changed, sr, engine.n_true)
+        dist, changed, it, done, spmv, consumed = state
+        nz = nonzeros_of(changed, sr, engine.n_true)
+        density = nz.astype(jnp.float32) / float(engine.n_true)
         used = jnp.where(policy == "spmv", 1,
                          jnp.where(policy == "spmspv", 0,
                                    (density > engine.threshold).astype(jnp.int32)))
@@ -47,19 +50,16 @@ def sssp(engine: GraphEngine, source: int, max_iters: int = 64,
         new_dist = jnp.minimum(dist, cand)
         new_changed = jnp.where(new_dist < dist, new_dist, jnp.inf)
         done = jnp.sum(new_changed != jnp.inf) == 0
-        dens = dens.at[it].set(density)
-        kern = kern.at[it].set(used)
-        return (new_dist, new_changed, it + 1, done, dens, kern)
+        return (new_dist, new_changed, it + 1, done, spmv + used,
+                consumed + nz)
 
     dist0 = jnp.full((n,), jnp.inf, jnp.float32).at[source].set(0.0)
     changed0 = jnp.full((n,), jnp.inf, jnp.float32).at[source].set(0.0)
-    dens0 = jnp.full((max_iters,), -1.0, jnp.float32)
-    kern0 = jnp.full((max_iters,), -1, jnp.int32)
+    zero = jnp.asarray(0, jnp.int32)
 
-    dist, changed, it, done, dens, kern = jax.lax.while_loop(
-        cond, body, (dist0, changed0, jnp.asarray(0, jnp.int32),
-                     jnp.asarray(False), dens0, kern0))
-    return SSSPResult(dist[: engine.n_true], it, dens, kern)
+    dist, changed, it, done, spmv, consumed = jax.lax.while_loop(
+        cond, body, (dist0, changed0, zero, jnp.asarray(False), zero, zero))
+    return SSSPResult(dist[: engine.n_true], it, spmv, consumed)
 
 
 def sssp_reference(rows: np.ndarray, cols: np.ndarray, weights: np.ndarray,

@@ -104,7 +104,7 @@ class _LiveSpan:
     profiler ``TraceAnnotation`` when a session is active), exit stamps
     t1 and hands the record to the tracer, if there is one.
     ``set(**attrs)`` adds attributes mid-flight (e.g. bytes known only
-    after the phase ran); they reach the Tracer, not the annotation."""
+    after the phase ran); they reach the Tracer and the annotation."""
 
     __slots__ = ("_tracer", "name", "attrs", "t0", "t1", "_annotation")
 
@@ -136,6 +136,8 @@ class _LiveSpan:
 
     def set(self, **attrs) -> "_LiveSpan":
         self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
         return self
 
 

@@ -98,6 +98,9 @@ from repro.serve.scheduler import (
 
 ALGORITHMS = ("bfs", "sssp", "ppr")
 GLOBAL_ALGORITHMS = ("pagerank", "cc", "triangles", "kcore")
+# Kinds whose answers depend on ``max_iters``, their stated stopping rule;
+# the rest run to their fixpoint.
+ITERATION_BOUNDED = ("ppr", "pagerank")
 GLOBAL = -1  # source sentinel for global (whole-graph) requests
 
 
@@ -219,6 +222,8 @@ class GraphQueryServer:
         self.graph = graph
         self.stump = stump or trained_stump()
         self.batch_size = batch_size
+        # PPR's and PageRank's iteration limit, part of their stopping
+        # rule; BFS and SSSP run to their fixpoint at any depth.
         self.max_iters = max_iters
         self.policy = policy
         self.alpha = alpha
@@ -266,12 +271,21 @@ class GraphQueryServer:
         engine parameters. Everything that changes answers must be in it:
         the graph's edge content plus the engine-shaping parameters — the
         stump included, since it moves the adaptive switch point and with
-        it the kernels' float accumulation order."""
+        it the kernels' float accumulation order. ``max_iters`` shapes
+        the ``ITERATION_BOUNDED`` kinds alone, so :meth:`_cache_key` adds
+        it to theirs."""
         stump_key = (f"{self.stump.feature}:{self.stump.threshold:g}:"
                      f"{self.stump.left_class}:{self.stump.right_class}")
         return (f"{graph_fingerprint(graph)}"
-                f"/w{self.weight_seed}/a{self.alpha}/i{self.max_iters}"
+                f"/w{self.weight_seed}/a{self.alpha}"
                 f"/{self.policy}/s{stump_key}")
+
+    def _cache_key(self, algorithm: str, source: int) -> Tuple[str, str, int]:
+        """LRU key of one answer: the engine key, the kind (with
+        ``max_iters`` for the kinds it bounds) and the source."""
+        if algorithm in ITERATION_BOUNDED:
+            algorithm = f"{algorithm}/i{self.max_iters}"
+        return (self.engine_key, algorithm, source)
 
     def stats(self) -> Dict[str, Any]:
         """One coherent counter snapshot: the server's serving/mutation
@@ -426,6 +440,7 @@ class GraphQueryServer:
         payload_field = {"bfs": "levels", "sssp": "dist", "ppr": "rank"}
 
         def keep(algorithm: str, source: int, payload: Dict[str, Any]) -> bool:
+            algorithm = algorithm.split("/")[0]
             if source == GLOBAL or algorithm not in payload_field:
                 return False    # whole-graph answers see every edge
             vals = np.asarray(payload[payload_field[algorithm]])[touched]
@@ -503,9 +518,9 @@ class GraphQueryServer:
         eng = self.engine(algorithm)
         chunks = [misses[lo: lo + self.batch_size]
                   for lo in range(0, len(misses), self.batch_size)]
-        kw = dict(policy=self.policy, max_iters=self.max_iters)
+        kw = dict(policy=self.policy)
         if algorithm == "ppr":
-            kw["alpha"] = self.alpha
+            kw.update(alpha=self.alpha, max_iters=self.max_iters)
 
         # materialize runs inside the pipeline's overlap window, so
         # payload conversion of bucket t happens while bucket t+1
@@ -518,8 +533,11 @@ class GraphQueryServer:
             # the bucket's wait-for-compute (the first host pull blocks
             # on the device result) apart from the payload conversion
             with trace.span("serve/bucket_compute", algorithm=algorithm,
-                            rows=len(bucket)):
-                rows, iters = self._to_host(algorithm, res)
+                            rows=len(bucket)) as span:
+                rows, iters, spmv = self._to_host(algorithm, res)
+                trips = int(iters.max())
+                span.set(trips=trips)
+            self._count_trips(trips, iters[:len(bucket)], spmv[:len(bucket)])
             with trace.span("serve/payload", algorithm=algorithm,
                             rows=len(bucket)):
                 out = self._payloads(rows, iters, bucket)
@@ -536,10 +554,23 @@ class GraphQueryServer:
             out.update(payloads)
         return out
 
+    def _count_trips(self, trips: int, iters: np.ndarray,
+                     spmv: np.ndarray) -> None:
+        """One bucket's loop trips into the registry (``stats()
+        ["latency"]``): the ``bucket_trips`` histogram, and per query row
+        the trips the density switch sent to the SpMSpV ladder
+        (``row_trips_spmspv``) and to the SpMV (``row_trips_spmv``)."""
+        reg = self.metrics
+        reg.histogram("bucket_trips", least=1.0).observe(float(trips))
+        on_spmv = int(spmv.sum())
+        reg.counter("row_trips_spmv").inc(on_spmv)
+        reg.counter("row_trips_spmspv").inc(int(iters.sum()) - on_spmv)
+
     @staticmethod
     def _to_host(algorithm: str, res) -> Tuple[Dict[str, np.ndarray],
-                                               np.ndarray]:
-        """Pull one bucket's device result to host arrays. The first
+                                               np.ndarray, np.ndarray]:
+        """Pull one bucket's device result to host arrays: the payload
+        rows, each row's loop trips and its trips on the SpMV. The first
         ``np.asarray`` blocks on the bucket's traversal, so this is the
         wait-for-compute half of materialisation (traced as
         ``serve/bucket_compute``)."""
@@ -550,7 +581,7 @@ class GraphQueryServer:
         else:
             rows = {"rank": np.asarray(res.rank),
                     "residual": np.asarray(res.residual)}
-        return rows, np.asarray(res.iterations)
+        return rows, np.asarray(res.iterations), np.asarray(res.spmv_trips)
 
     @staticmethod
     def _payloads(rows: Dict[str, np.ndarray], iters: np.ndarray,
@@ -563,14 +594,6 @@ class GraphQueryServer:
             payload["iterations"] = int(iters[i])
             out[s] = payload
         return out
-
-    @classmethod
-    def _materialize(cls, algorithm: str, res, sources: List[int]
-                     ) -> Dict[int, Dict[str, Any]]:
-        """One bucket's device result -> host payload dicts, keyed by
-        source (= _to_host + _payloads in one step)."""
-        rows, iters = cls._to_host(algorithm, res)
-        return cls._payloads(rows, iters, sources)
 
     def _run_global(self, algorithm: str) -> Dict[str, Any]:
         """One whole-graph analytics run (computed at most once per graph
@@ -660,7 +683,7 @@ class GraphQueryServer:
                     # put, and from the local payload (counted as dedup, like
                     # the traversal path) when caching is disabled/evicting —
                     # the compute-once contract never depends on the cache.
-                    key = (self.engine_key, algorithm, GLOBAL)
+                    key = self._cache_key(algorithm, GLOBAL)
                     fresh = None
                     for req in reqs:
                         hit = self.cache.get(key)
@@ -681,7 +704,8 @@ class GraphQueryServer:
                 misses: List[int] = []
                 seen = set()
                 for req in reqs:
-                    hit = self.cache.get((self.engine_key, algorithm, req.source))
+                    hit = self.cache.get(self._cache_key(algorithm,
+                                                         req.source))
                     if hit is not None:
                         # shallow copy: the dict is per-request, the numpy
                         # payloads stay shared (treat them as read-only)
@@ -696,7 +720,7 @@ class GraphQueryServer:
                 fresh: Dict[int, Dict[str, Any]] = (
                     self._run_batches(algorithm, misses) if misses else {})
                 for src, payload in fresh.items():
-                    self.cache.put((self.engine_key, algorithm, src), payload)
+                    self.cache.put(self._cache_key(algorithm, src), payload)
                 for req in reqs:
                     if req.result is None:
                         req.result = dict(fresh[req.source])

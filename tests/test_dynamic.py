@@ -22,8 +22,6 @@ from repro.graphs.engine import build_engine, content_keyed_weights
 from repro.graphs.multi import bfs_multi, relax_multi, sssp_multi
 from repro.graphs.ppr import pagerank
 
-MAX_IT = 256
-
 
 def _from_scratch(undirected_pairs, n, name="scratch") -> Graph:
     """Datasets-style construction over an undirected edge list: the
@@ -193,13 +191,12 @@ def test_bfs_sssp_incremental_exact(base, kind):
     rng = np.random.default_rng(2)
     srcs = [int(s) for s in rng.integers(0, base.n, 3)]
 
-    old_lv = np.asarray(bfs_multi(build_engine(base, BOOL_OR_AND), srcs,
-                                  max_iters=MAX_IT).levels)
+    old_lv = np.asarray(
+        bfs_multi(build_engine(base, BOOL_OR_AND), srcs).levels)
     e1_unit = build_engine(g1, MIN_PLUS, weighted=False)
     repair = plan_repair(e1_unit, d)
-    inc = bfs_incremental(e1_unit, srcs, old_lv, d, repair=repair,
-                          max_iters=MAX_IT)
-    cold = bfs_multi(build_engine(g1, BOOL_OR_AND), srcs, max_iters=MAX_IT)
+    inc = bfs_incremental(e1_unit, srcs, old_lv, d, repair=repair)
+    cold = bfs_multi(build_engine(g1, BOOL_OR_AND), srcs)
     np.testing.assert_array_equal(inc.values, np.asarray(cold.levels))
     assert inc.values.dtype == np.int32
 
@@ -207,10 +204,9 @@ def test_bfs_sssp_incremental_exact(base, kind):
                         content_keyed=True)
     e1_w = build_engine(g1, MIN_PLUS, weighted=True, seed=5,
                         content_keyed=True)
-    old_d = np.asarray(sssp_multi(e0_w, srcs, max_iters=MAX_IT).dist)
-    inc_w = sssp_incremental(e1_w, srcs, old_d, d, repair=repair,
-                             max_iters=MAX_IT)
-    cold_w = sssp_multi(e1_w, srcs, max_iters=MAX_IT)
+    old_d = np.asarray(sssp_multi(e0_w, srcs).dist)
+    inc_w = sssp_incremental(e1_w, srcs, old_d, d, repair=repair)
+    cold_w = sssp_multi(e1_w, srcs)
     np.testing.assert_array_equal(inc_w.values, np.asarray(cold_w.dist))
     assert inc_w.traffic > 0 or d.n_inserts + d.n_deletes == 0
     assert traffic_of(cold_w) > 0
@@ -237,9 +233,9 @@ def test_empty_delta_incremental_is_free(base):
     d = canonicalize(EdgeDelta(), base.n)
     srcs = [1, 5]
     e_unit = build_engine(base, MIN_PLUS, weighted=False)
-    old_lv = np.asarray(bfs_multi(build_engine(base, BOOL_OR_AND), srcs,
-                                  max_iters=MAX_IT).levels)
-    inc = bfs_incremental(e_unit, srcs, old_lv, d, max_iters=MAX_IT)
+    old_lv = np.asarray(
+        bfs_multi(build_engine(base, BOOL_OR_AND), srcs).levels)
+    inc = bfs_incremental(e_unit, srcs, old_lv, d)
     np.testing.assert_array_equal(inc.values, old_lv)
     assert inc.traffic == 0.0 and inc.repair.traffic == 0.0
 
@@ -265,14 +261,16 @@ def test_relax_multi_cold_seed_equals_sssp_multi(base):
     srcs = [3, 11, 42]
     d0 = np.full((3, base.n), np.inf, np.float32)
     d0[np.arange(3), srcs] = 0.0
-    got = relax_multi(eng, d0, d0.copy(), max_iters=MAX_IT)
-    want = sssp_multi(eng, srcs, max_iters=MAX_IT)
+    got = relax_multi(eng, d0, d0.copy())
+    want = sssp_multi(eng, srcs)
     np.testing.assert_array_equal(np.asarray(got.dist),
                                   np.asarray(want.dist))
     np.testing.assert_array_equal(np.asarray(got.iterations),
                                   np.asarray(want.iterations))
-    np.testing.assert_array_equal(np.asarray(got.kernel_used),
-                                  np.asarray(want.kernel_used))
+    np.testing.assert_array_equal(np.asarray(got.spmv_trips),
+                                  np.asarray(want.spmv_trips))
+    np.testing.assert_array_equal(np.asarray(got.consumed),
+                                  np.asarray(want.consumed))
 
 
 # ---------------------------------------------------------------------------

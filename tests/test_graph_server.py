@@ -167,6 +167,22 @@ def test_global_submit_validation(server):
         server.submit("triangles", 3)
 
 
+def test_shared_cache_keys_max_iters_by_kind(graph):
+    """``max_iters`` bounds PPR alone: two servers that differ only in it
+    share their BFS answers through one cache, never their PPR ones."""
+    shared = LRUCache(128)
+    s1 = GraphQueryServer(graph, batch_size=4, cache=shared, max_iters=64)
+    s2 = GraphQueryServer(graph, batch_size=4, cache=shared, max_iters=8)
+    assert s1.engine_key == s2.engine_key
+    a, p = s1.submit("bfs", 3), s1.submit("ppr", 3)
+    s1.flush()
+    b, q = s2.submit("bfs", 3), s2.submit("ppr", 3)
+    s2.flush()
+    assert b.cached and not q.cached
+    np.testing.assert_array_equal(b.result["levels"], a.result["levels"])
+    assert q.result["iterations"] <= 8 < p.result["iterations"]
+
+
 def test_shared_cache_keys_by_graph_identity(graph):
     """Regression (ISSUE 2 satellite): one cache serving two graphs (or a
     rebuilt engine) must never return stale cross-graph results."""

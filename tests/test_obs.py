@@ -184,6 +184,25 @@ def test_live_span_writes_the_profiler_trace(tmp_path):
     assert recorded.attrs == {"algorithm": "bfs", "rows": 8}
 
 
+def test_span_set_reaches_the_profiler_trace(tmp_path):
+    """An attribute known only inside the span (a bucket's loop trips)
+    lands on the profiler's event beside those given at entry, and in
+    the Tracer."""
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.tracing() as t:
+            with trace.span("serve/bucket_compute", rows=8) as s:
+                s.set(trips=417)
+    finally:
+        jax.profiler.stop_trace()
+    event, = _profiled_events(tmp_path, "serve/bucket_compute")
+    assert {("rows", 8), ("trips", 417)} <= set(event.stats)
+    recorded, = t.filter("serve/bucket_compute")
+    assert recorded.attrs == {"rows": 8, "trips": 417}
+
+
 # ---------------------------------------------------------------------------
 # trace: recording + export
 # ---------------------------------------------------------------------------
